@@ -84,7 +84,9 @@ def _run_real_lane(rows):
     rep = replay(
         trace, REAL_NODES, policy="cannikin", epochs_per_event=REAL_EPOCHS,
         steps=STEPS, seed=SEED,
-        real_backend=RealBackendConfig(arch="olmo-1b", seq_len=16, lr=0.3),
+        real_backend=RealBackendConfig(
+            arch="olmo-1b", reduced=True, seq_len=16, lr=0.3
+        ),
         faults=plan, invariants=True,
     )
     elapsed = time.perf_counter() - t0

@@ -24,12 +24,12 @@ def run() -> List[Row]:
     q = jax.random.normal(rng, (B, S, H, D))
     t_kernel = time_call(
         lambda: jax.block_until_ready(
-            flash_attention(q, q, q, block_q=64, block_k=64)
+            flash_attention(q, q, q, block_q=64, block_k=64, interpret=True)
         )
     )
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
     t_ref = time_call(lambda: jax.block_until_ready(attention_ref(fold(q), fold(q), fold(q))))
-    out = flash_attention(q, q, q, block_q=64, block_k=64)
+    out = flash_attention(q, q, q, block_q=64, block_k=64, interpret=True)
     ref = attention_ref(fold(q), fold(q), fold(q)).reshape(B, H, S, D).transpose(0, 2, 1, 3)
     err = float(jnp.abs(out - ref).max())
     rows.append(Row("kernel/flash_attention/interpret", t_kernel, f"err={err:.1e}"))
@@ -44,7 +44,7 @@ def run() -> List[Row]:
     v = jax.random.normal(ks[2], (1, T, Hh, K)) * 0.5
     lw = -jnp.exp(jax.random.normal(ks[3], (1, T, Hh, K)))
     u = jax.random.normal(ks[4], (Hh, K)) * 0.2
-    t_kernel = time_call(lambda: jax.block_until_ready(wkv(r, k, v, lw, u)[0]))
+    t_kernel = time_call(lambda: jax.block_until_ready(wkv(r, k, v, lw, u, interpret=True)[0]))
     rows.append(Row("kernel/rwkv6_wkv/interpret", t_kernel, ""))
 
     # ssm scan
@@ -55,7 +55,7 @@ def run() -> List[Row]:
     ct = jax.random.normal(ks[3], (1, T, N))
     la = jax.random.normal(ks[4], (Dd, N)) * 0.5
     t_kernel = time_call(
-        lambda: jax.block_until_ready(ssm_scan(u_in, dt, bt, ct, la, d_block=128)[0])
+        lambda: jax.block_until_ready(ssm_scan(u_in, dt, bt, ct, la, d_block=128, interpret=True)[0])
     )
     rows.append(Row("kernel/ssm_scan/interpret", t_kernel, ""))
 

@@ -105,7 +105,7 @@ def _fused_loop(*, fused: bool, sharded: bool, steps: int, seed: int = 0):
         backend="real",
     )
     backend = RealBackendConfig(
-        arch="olmo-1b", seq_len=16, lr=0.3, sharded=sharded
+        arch="olmo-1b", reduced=True, seq_len=16, lr=0.3, sharded=sharded
     ).build(noise=0.0, seed=seed)
     backend.configure(spec, tuple(range(FUSED_N_NODES)), seed=seed + 1)
     ctrl = CannikinController(

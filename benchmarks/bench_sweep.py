@@ -184,7 +184,7 @@ def run_jax(n: int, num_candidates: int, repeats: int) -> dict:
     return rec
 
 
-def run_scheduler(n_jobs: int, n_nodes: int, *, assert_gate: bool, jax_lane: bool) -> dict:
+def run_scheduler(n_jobs: int, n_nodes: int, *, assert_gate: bool) -> dict:
     """Scheduler lane: warm-started stacked allocation (NumPy batched and
     stacked-jax engines) vs the per-(job, node) scalar loop, one scalar
     baseline shared by both engine lanes."""
@@ -211,18 +211,17 @@ def run_scheduler(n_jobs: int, n_nodes: int, *, assert_gate: bool, jax_lane: boo
         "assignments_equal": a_b.assignment == a_s.assignment,
         "aggregate_fraction": a_b.aggregate_fraction,
     }
-    if jax_lane:
-        allocate(jobs, n_nodes, engine="jax")  # jit warmup outside the clock
-        rec["jax_us"] = timed("jax", repeats=3)
-        rec["jax_speedup"] = t_scalar / rec["jax_us"]
-        a_j = allocate(jobs, n_nodes, engine="jax")
-        rec["jax_assignments_equal"] = a_j.assignment == a_s.assignment
-        if not rec["jax_assignments_equal"]:
-            raise AssertionError(f"jax allocate diverged from scalar: {rec}")
-        if assert_gate and rec["jax_speedup"] < 10.0:
-            raise AssertionError(
-                f"stacked-jax allocate under 10x at {n_jobs}x{n_nodes}: {rec}"
-            )
+    allocate(jobs, n_nodes, engine="jax")  # jit warmup outside the clock
+    rec["jax_us"] = timed("jax", repeats=3)
+    rec["jax_speedup"] = t_scalar / rec["jax_us"]
+    a_j = allocate(jobs, n_nodes, engine="jax")
+    rec["jax_assignments_equal"] = a_j.assignment == a_s.assignment
+    if not rec["jax_assignments_equal"]:
+        raise AssertionError(f"jax allocate diverged from scalar: {rec}")
+    if assert_gate and rec["jax_speedup"] < 10.0:
+        raise AssertionError(
+            f"stacked-jax allocate under 10x at {n_jobs}x{n_nodes}: {rec}"
+        )
     if not rec["assignments_equal"]:
         raise AssertionError(f"batched allocate diverged from scalar: {rec}")
     if assert_gate and rec["speedup"] < 10.0:
@@ -329,28 +328,21 @@ def run(smoke: bool = False) -> List[Row]:
     )
 
     # JAX-engine lane (gate: <= 1e-5 vs the scalar oracle; CPU jit in CI).
-    try:
-        from repro.core.optperf_jax import HAS_JAX
-    except ImportError:
-        HAS_JAX = False
-    if HAS_JAX:
-        rec = run_jax(wn, wc, repeats)
-        payload["jax"] = rec
-        rows.append(
-            Row(
-                f"sweep/jax/n{wn}xc{wc}",
-                rec["jax_us"],
-                f"warm={rec['jax_warm_us']:.0f}us;"
-                f"gap={rec['max_rel_gap_vs_oracle']:.2e}",
-            )
+    rec = run_jax(wn, wc, repeats)
+    payload["jax"] = rec
+    rows.append(
+        Row(
+            f"sweep/jax/n{wn}xc{wc}",
+            rec["jax_us"],
+            f"warm={rec['jax_warm_us']:.0f}us;"
+            f"gap={rec['max_rel_gap_vs_oracle']:.2e}",
         )
-    else:
-        payload["jax"] = {"skipped": "jax unavailable"}
+    )
 
     # Scheduler lanes (gates: batched and stacked-jax both >= 10x at
     # 8 jobs x 64 nodes, assignments identical to the scalar oracle).
     sj, sn = (3, 12) if smoke else (8, 64)
-    rec = run_scheduler(sj, sn, assert_gate=not smoke, jax_lane=HAS_JAX)
+    rec = run_scheduler(sj, sn, assert_gate=not smoke)
     payload["scheduler"] = rec
     rows.append(
         Row(

@@ -72,7 +72,9 @@ def run_chaos_real(args) -> None:
             3,
             policy="cannikin",
             seed=0,
-            real_backend=RealBackendConfig(arch=args.arch, seq_len=16, lr=0.3),
+            real_backend=RealBackendConfig(
+                arch=args.arch, reduced=True, seq_len=16, lr=0.3
+            ),
             checkpoint_dir=workdir,
             faults=plan,
             invariants=True,
@@ -199,7 +201,9 @@ def main() -> None:
             3,
             policy="cannikin",
             seed=0,
-            real_backend=RealBackendConfig(arch=args.arch, seq_len=16, lr=0.3),
+            real_backend=RealBackendConfig(
+                arch=args.arch, reduced=True, seq_len=16, lr=0.3
+            ),
             checkpoint_dir=workdir,
         )
         handle = rt.submit(spec, at=0.0)

@@ -1,11 +1,13 @@
 """Assigned-architecture configs.
 
 ``ARCHS`` maps arch id -> config module; `get_api(arch_id, reduced=False)`
-returns a ready `ModelApi`.
+returns a ready `ModelApi`
+(``n_layers=`` cuts the depth and keeps every width).
 """
 from __future__ import annotations
 
-from typing import Dict, List
+import dataclasses
+from typing import Dict, List, Optional
 
 from repro.configs import (
     chameleon_34b,
@@ -42,11 +44,17 @@ def arch_ids() -> List[str]:
     return list(ARCHS.keys())
 
 
-def get_api(arch_id: str, *, reduced: bool = False) -> ModelApi:
+def get_api(
+    arch_id: str, *, reduced: bool = False, n_layers: Optional[int] = None
+) -> ModelApi:
+    """The arch's published config (or its small ``reduced`` preset), with
+    the depth cut to ``n_layers`` when given; every width is kept."""
     if arch_id not in ARCHS:
         raise KeyError(f"unknown arch {arch_id!r}; available: {sorted(ARCHS)}")
     mod = ARCHS[arch_id]
     cfg = mod.reduced() if reduced else mod.config()
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=int(n_layers))
     return build_api(arch_id, cfg)
 
 

@@ -350,13 +350,9 @@ class CannikinController:
         cache is keyed on the frozen model instance)."""
         if self.selector.engine != "jax":
             return
-        try:
-            from repro.core import optperf_jax
+        from repro.core import optperf_jax
 
-            if optperf_jax.HAS_JAX:
-                optperf_jax.device_coeffs(model)
-        except ImportError:  # pragma: no cover - jax present in CI image
-            pass
+        optperf_jax.device_coeffs(model)
 
     def _evict_device_export(self) -> None:
         """Drop the *current* model's cached device-coefficient export.
@@ -367,13 +363,9 @@ class CannikinController:
         stays pinned in device memory) after the cluster changed shape."""
         if self._model is None:
             return
-        try:
-            from repro.core import optperf_jax
+        from repro.core import optperf_jax
 
-            if optperf_jax.HAS_JAX:
-                optperf_jax.evict_device_coeffs(self._model)
-        except ImportError:  # pragma: no cover - jax present in CI image
-            pass
+        optperf_jax.evict_device_coeffs(self._model)
 
     def set_comm_split(self, t_o: float, t_u: float, gamma: float) -> None:
         """Override the comm model with directly measured values (used when the
@@ -506,8 +498,8 @@ class CannikinController:
     def fused_context(self) -> Optional[FusedSweepContext]:
         """The device-side sweep inputs for a fused backend epoch, or None
         whenever fused mode cannot run (bootstrap, non-adaptive, a policy
-        other than cannikin-gns, jax missing, or a past certification
-        failure) — callers then take the two-program path unchanged."""
+        other than cannikin-gns, or a past certification failure) —
+        callers then take the two-program path unchanged."""
         if not self.adaptive or self._fused_disabled:
             return None
         if getattr(self.policy, "name", "") != "cannikin-gns":
@@ -517,14 +509,9 @@ class CannikinController:
             return None
         if self._fused_ctx is not None and self._fused_ctx.model is model:
             return self._fused_ctx
-        try:
-            from repro.core import optperf_jax
-        except ImportError:  # pragma: no cover - jax present in CI image
-            return None
-        if not optperf_jax.HAS_JAX:
-            return None
         import jax.numpy as jnp
 
+        from repro.core import optperf_jax
         from repro.core.optperf import _problem_from_model  # noqa: SLF001
 
         coeffs = optperf_jax.device_coeffs(model)

@@ -78,18 +78,13 @@ def goodput(
 
 def _array_sweep_solver(engine: str):
     """The array-engine entry point for a full sweep: the jit on-device
-    solver for ``engine == "jax"`` (silently falling back to the NumPy
-    batched engine when JAX is unavailable), else the NumPy batched engine.
-    Shared gating for :func:`goodput_curve` and :class:`BatchSizeSelector`.
+    solver for ``engine == "jax"``, else the NumPy batched engine.  Shared
+    gating for :func:`goodput_curve` and :class:`BatchSizeSelector`.
     """
     if engine == "jax":
-        try:
-            from repro.core import optperf_jax
+        from repro.core import optperf_jax
 
-            if optperf_jax.HAS_JAX:
-                return optperf_jax.solve_optperf_batch_jax
-        except ImportError:  # pragma: no cover - jax present in CI image
-            pass
+        return optperf_jax.solve_optperf_batch_jax
     return solve_optperf_batch
 
 

@@ -48,16 +48,12 @@ warm starts stay correct while valid ones cut the fixed trip count from
 ``max_iter`` to ``warm_max_iter``.
 
 Precision: the device sweep runs in float32 unless x64 is enabled (pass
-``dtype`` or run under ``jax.experimental.enable_x64``).  The emitted
+``dtype`` or run under ``jax.enable_x64``).  The emitted
 ``t_stars`` are certified and finalized *on the host in float64* through the
 exact same :func:`repro.core.optperf._finalize_batches` path as the NumPy
 engine, so partitions sum exactly and the two engines agree to the device
 dtype's resolution (<= 1e-5 relative for float32, ~1e-10 for float64);
 winners re-solved by the scalar oracle are identical across all engines.
-
-JAX is an optional dependency of the core: when it is missing ``HAS_JAX`` is
-False and :class:`~repro.core.goodput.BatchSizeSelector` silently falls back
-to the NumPy batched engine.
 """
 from __future__ import annotations
 
@@ -66,7 +62,10 @@ import functools
 import zlib
 from typing import NamedTuple, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.optperf import (
     BatchedOptPerfSolution,
@@ -80,20 +79,7 @@ from repro.core.optperf import (
 )
 from repro.core.perf_model import ClusterPerfModel, StackedClusterModel
 
-try:  # pragma: no cover - import success is the covered path in this image
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    HAS_JAX = True
-except Exception:  # pragma: no cover - gated fallback for jax-less installs
-    jax = None  # type: ignore[assignment]
-    jnp = None  # type: ignore[assignment]
-    lax = None  # type: ignore[assignment]
-    HAS_JAX = False
-
 __all__ = [
-    "HAS_JAX",
     "DeviceCoeffs",
     "StackedDeviceCoeffs",
     "device_coeffs",
@@ -219,10 +205,8 @@ def device_coeffs(model: ClusterPerfModel, dtype=None) -> DeviceCoeffs:
 
     ``dtype`` defaults to float64 under x64 and float32 otherwise.  The cache
     is keyed on the frozen model *and* the dtype, so flipping x64 mid-process
-    (e.g. ``jax.experimental.enable_x64``) never serves stale-width arrays.
+    (e.g. ``jax.enable_x64``) never serves stale-width arrays.
     """
-    if not HAS_JAX:
-        raise RuntimeError("jax is not available; use the NumPy batched engine")
     if dtype is None:
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     return _device_coeffs_cached(model, np.dtype(dtype).name)
@@ -375,8 +359,6 @@ def solve_optperf_sweep_device(
     entry a device-dtype OptPerf estimate for its candidate total — and the
     bisection trip count actually spent.
     """
-    if not HAS_JAX:
-        raise RuntimeError("jax is not available; use the NumPy batched engine")
     dt = coeffs.alphas.dtype
     totals = jnp.asarray(total_batches, dt)
     lo0_dev = jnp.asarray(lo0, dt)
@@ -449,8 +431,6 @@ def solve_optperf_batch_jax(
     (~log2(2*delta/tol) steps for a valid ±delta seed) and only spends the
     full budget when a stale seed forced the bracket open.
     """
-    if not HAS_JAX:
-        raise RuntimeError("jax is not available; use the NumPy batched engine")
     totals_np = _validated_totals(total_batches)
     model.validate()
     dc = device_coeffs(model, dtype)
@@ -547,8 +527,6 @@ def stacked_device_coeffs(stack: StackedClusterModel, dtype=None) -> StackedDevi
     derived cache (device exports, solver problem view, validation memo) is
     dropped, and a fresh export of the refreshed numbers is shipped.
     """
-    if not HAS_JAX:
-        raise RuntimeError("jax is not available; use the NumPy stacked engine")
     if dtype is None:
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     key = np.dtype(dtype).name
@@ -626,8 +604,6 @@ def solve_optperf_stacked_jax(
     stacked engines agree to the device dtype's resolution (<= 1e-5 relative
     in float32).
     """
-    if not HAS_JAX:
-        raise RuntimeError("jax is not available; use the NumPy stacked engine")
     totals_np = _validated_totals(total_batches)
     if totals_np.shape[0] != stack.shape[0]:
         raise ValueError("total_batches length must match stack rows")

@@ -195,16 +195,11 @@ def _chosen_goodput_batch(
 
 def _stacked_solver(engine: str):
     """The stacked-row solver for an array engine: the jit on-device kernel
-    for ``engine == "jax"`` (silently falling back to the NumPy stacked
-    engine when JAX is unavailable), else the NumPy stacked engine."""
+    for ``engine == "jax"``, else the NumPy stacked engine."""
     if engine == "jax":
-        try:
-            from repro.core import optperf_jax
+        from repro.core import optperf_jax
 
-            if optperf_jax.HAS_JAX:
-                return optperf_jax.solve_optperf_stacked_jax
-        except ImportError:  # pragma: no cover - jax present in CI image
-            pass
+        return optperf_jax.solve_optperf_stacked_jax
     return solve_optperf_stacked
 
 

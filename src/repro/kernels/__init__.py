@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the compute hot spots of the model zoo.
 
 Each kernel ships: <name>.py (pl.pallas_call + BlockSpec VMEM tiling),
-ops.py (jit-ready public wrapper), ref.py (pure-jnp oracle).  Validated in
-interpret=True mode on CPU; identical kernel bodies target the TPU MXU/VPU.
+ops.py (jit-ready public wrapper), ref.py (pure-jnp oracle).  Every entry
+point compiles for the TPU by default (``interpret=False``); the CPU tests
+pass ``interpret=True`` to run the same kernel bodies in the interpreter.
 
 The paper's own contribution (Cannikin) is a scheduling/estimation layer —
 it has no kernel; these cover the substrate it trains (DESIGN.md §6).

@@ -62,23 +62,24 @@ def _flash_kernel(
         mask = mask & (k_pos > q_pos - window)
     scores = jnp.where(mask, scores, NEG_INF)
 
+    # Row statistics stay 2-D (bq, 1): Mosaic cannot reshape a 1-D vector
+    # (least of all a bool one) into a column.
     m_prev = m_ref[...]
     l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1))
+    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+    # A row with no valid key yet has m_prev == m_new == NEG_INF, so
+    # alpha = exp(0) = 1 and the masked p below keeps it at zero.
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new[:, None])
-    # Rows with no valid key yet: keep everything at zero.
-    p = jnp.where((m_new == NEG_INF)[:, None], 0.0, p)
-    alpha = jnp.where(m_new == NEG_INF, 1.0, alpha)
-    l_ref[...] = l_prev * alpha + p.sum(axis=-1)
+    p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+    l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
     m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     @pl.when(ik == n_kv_blocks - 1)
     def finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
+        denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
@@ -92,7 +93,7 @@ def flash_attention_kernel(
     softmax_scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
     kv_len: Optional[int] = None,
 ) -> jax.Array:
     bh, s, d = q.shape
@@ -123,8 +124,8 @@ def flash_attention_kernel(
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),   # running max
-            pltpu.VMEM((block_q,), jnp.float32),   # running denom
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
             pltpu.VMEM((block_q, d), jnp.float32), # accumulator
         ],
         interpret=interpret,

@@ -2,8 +2,8 @@
 
 Accepts model-layout tensors (B, S, H, D) with GQA kv heads, folds
 batch*heads, pads sequences to block multiples, and dispatches to the
-Pallas kernel.  ``interpret`` defaults to True because this container's
-backend is CPU; on TPU pass interpret=False (same kernel body).
+Pallas kernel.  ``interpret=True`` runs the same kernel body in the Pallas
+interpreter (the CPU backend); the default compiles it for the TPU.
 """
 from __future__ import annotations
 
@@ -30,7 +30,7 @@ def flash_attention(
     softmax_scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     b, s, h, d = q.shape
     t, kv = k.shape[1], k.shape[2]

@@ -44,9 +44,17 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_final_ref, state_re
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = jnp.clip(lw_ref[0].astype(jnp.float32), LOG_DECAY_MIN, 0.0)
-    u = u_ref[0].astype(jnp.float32)          # (1, K) block of (H, K)
+    u = u_ref[0].astype(jnp.float32)          # (1, K) block of (BH, 1, K)
 
-    l_inc = jnp.cumsum(lw, axis=0)            # L_t inclusive
+    # L_t inclusive, as a lower-triangular matmul: Mosaic has no cumsum.
+    # HIGHEST keeps the f32 log-decays exact through the MXU passes.
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    l_inc = jax.lax.dot_general(
+        jnp.where(s_idx <= t_idx, 1.0, 0.0), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
     l_prev = l_inc - lw                       # L_{t-1}
     l_end = l_inc[-1:, :]                     # (1, K)
 
@@ -58,8 +66,6 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_final_ref, state_re
     scores = jax.lax.dot_general(
         rr, kk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                         # (C, C): scores[t, s]
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(s_idx < t_idx, scores, 0.0)  # strictly lower triangular
 
     diag = jnp.sum(r * u * k, axis=1)         # bonus term: r_t . (u . k_t)
@@ -88,10 +94,10 @@ def wkv_kernel(
     k: jax.Array,
     v: jax.Array,
     log_w: jax.Array,
-    u: jax.Array,   # (BH, K) per-head bonus (broadcast over batch upstream)
+    u: jax.Array,   # (BH, 1, K) per-head bonus (broadcast over batch upstream)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     """Returns (out (BH, T, K), final state (BH, K, K))."""
     bh, t, kk = r.shape
@@ -107,7 +113,9 @@ def wkv_kernel(
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, kk), lambda b, c: (b, 0)),
+            # (1, 1, K): the last two dims equal the array's, which the
+            # (8, 128) tiling rule accepts for any K.
+            pl.BlockSpec((1, 1, kk), lambda b, c: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, kk), lambda b, c: (b, c, 0)),

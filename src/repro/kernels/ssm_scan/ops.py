@@ -7,7 +7,7 @@ from repro.kernels.ssm_scan.ssm_scan import ssm_scan_kernel
 
 
 def ssm_scan(u, dt, b_t, c_t, log_a, *, chunk: int = 64, d_block: int = 512,
-             interpret: bool = True):
+             interpret: bool = False):
     bsz, t, d = u.shape
     c = min(chunk, t)
     pad = (-t) % c

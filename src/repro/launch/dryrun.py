@@ -31,10 +31,9 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES, arch_ids, get_api
-from repro.launch.hlo_stats import analyze_hlo, raw_cost_analysis
+from repro.launch.hlo_stats import analyze_hlo
 from repro.sharding.context import sharding_context
 from repro.launch.mesh import (
-    enter_mesh,
     make_production_mesh,
     make_rules,
     train_microbatches,
@@ -120,7 +119,7 @@ def _memory_analysis_dict(compiled) -> Dict[str, Any]:
 
 def _cost_analysis_dict(compiled) -> Dict[str, Any]:
     try:
-        ca = raw_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
     except Exception as e:  # pragma: no cover
         return {"error": str(e)}
     keep = ("flops", "transcendentals", "bytes accessed")
@@ -283,7 +282,7 @@ def run_one(arch_id: str, shape_name: str, mesh_kind: str, outdir: str, *, force
     t0 = time.time()
     try:
         fn, args, in_shardings, out_shardings = build_dryrun(api, shape, mesh, rules)
-        with enter_mesh(mesh), sharding_context(mesh, rules):
+        with jax.set_mesh(mesh), sharding_context(mesh, rules):
             jitted = jax.jit(
                 fn,
                 in_shardings=in_shardings,

@@ -29,24 +29,8 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["HloStats", "analyze_hlo", "raw_cost_analysis"]
+__all__ = ["HloStats", "analyze_hlo"]
 
-
-def raw_cost_analysis(compiled) -> Dict[str, float]:
-    """Version-portable ``compiled.cost_analysis()``.
-
-    Older jax (< 0.5) returns a one-element *list* of dicts; newer releases
-    return the dict directly (and may return ``None`` when the backend has
-    no cost model).  Callers comparing the raw XLA numbers against the
-    trip-count-corrected :func:`analyze_hlo` should use this accessor so
-    the comparison works across jax versions.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
 
 DTYPE_BYTES = {
     "pred": 0.125, "s2": 0.25, "u2": 0.25, "s4": 0.5, "u4": 0.5,

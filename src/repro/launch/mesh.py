@@ -14,7 +14,6 @@ import jax
 from repro.sharding.rules import MeshRules
 
 __all__ = [
-    "enter_mesh",
     "make_production_mesh",
     "make_node_mesh",
     "make_rules",
@@ -24,23 +23,6 @@ __all__ = [
     "TRAIN_MICROBATCHES",
 ]
 
-
-def enter_mesh(mesh):
-    """Version-portable mesh context manager.
-
-    ``jax.set_mesh`` (the context-manager form) only exists in newer jax
-    releases; 0.5.x has ``jax.sharding.use_mesh``; on 0.4.x neither exists
-    and the ``Mesh`` object itself is the context manager that activates
-    the global mesh for jit/with_sharding_constraint resolution.  Returns a
-    context manager for ``with enter_mesh(mesh): ...``.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
 
 # Archs whose parameter+optimizer state exceeds per-chip HBM under 16-way TP
 # alone: shard the d_model dim of large matrices over the data axis (FSDP /
@@ -74,9 +56,14 @@ def train_microbatches(arch_id: str, *, global_batch: int = 256,
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """The pod mesh with Auto axes: the sharding rules place arrays with
+    bare PartitionSpecs and let XLA propagate the rest, which
+    ``jax.make_mesh``'s default Explicit axes would refuse."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -4,15 +4,19 @@ Three modes, each supporting ``--backend {real,sim}`` where it applies:
 
 * ``hetero`` (default) — the paper's end-to-end scenario driven through
   the shared ``EpochLoop``: with ``--backend real`` (default), real JAX
-  training of a reduced-config model on this host with per-node timing
+  training of the model on this host with per-node timing
   supplied by the calibrated heterogeneous-cluster simulator; with
   ``--backend sim``, the identical loop over the timing simulator alone
   (no gradients — losses are NaN, useful for fast policy/timing studies).
   The chosen policy (cannikin / even / lb-bsp / adaptdl) controls the
   batch partition and, for the adaptive policies, the total batch size.
 
-* ``spmd`` — single-process pjit training of a reduced config on the local
-  device(s): the quickstart path (examples/quickstart.py wraps it).
+* ``spmd`` — single-process pjit training on the local device(s): the
+  quickstart path (examples/quickstart.py wraps it).
+
+The model is ``--arch`` at its published widths; ``--layers`` cuts its
+depth to fit a chip, and ``--reduced`` builds the arch's small CPU preset
+instead (the size the CPU examples below use).
 
 * ``trace`` — multi-job cluster churn through the
   ``repro.runtime.ClusterRuntime`` front door: a seeded synthetic trace
@@ -26,12 +30,14 @@ Three modes, each supporting ``--backend {real,sim}`` where it applies:
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --policy cannikin \
-      --cluster B --epochs 12 --steps-per-epoch 8
-  PYTHONPATH=src python -m repro.launch.train --mode spmd --arch rwkv6-7b --steps 20
+      --cluster B --epochs 12 --steps-per-epoch 8 --reduced --seq-len 32
+  PYTHONPATH=src python -m repro.launch.train --mode spmd --arch rwkv6-7b \
+      --steps 20 --reduced --seq-len 32
   PYTHONPATH=src python -m repro.launch.train --mode trace --trace-jobs 3 \
       --trace-nodes 12 --epochs-per-event 2 --arrival poisson
   PYTHONPATH=src python -m repro.launch.train --mode trace --backend real \
-      --trace-jobs 1 --trace-nodes 3 --epochs-per-event 2 --ref-batch 16
+      --trace-jobs 1 --trace-nodes 3 --epochs-per-event 2 --ref-batch 16 \
+      --reduced --seq-len 32
 """
 from __future__ import annotations
 
@@ -102,7 +108,7 @@ def run_hetero(args) -> int:
         from repro.optim import constant_schedule, sgd
         from repro.runtime import RealBackend
 
-        api = get_api(args.arch, reduced=True)
+        api = get_api(args.arch, reduced=args.reduced, n_layers=args.layers)
         data = SyntheticLM(vocab=api.cfg.vocab, seq_len=args.seq_len, seed=args.seed)
         backend = RealBackend(
             api, sgd(constant_schedule(args.lr)), data, cluster=sim, seed=args.seed
@@ -151,7 +157,7 @@ def run_spmd(args) -> int:
     from repro.optim import adamw, constant_schedule
     from repro.train.step import build_train_step
 
-    api = get_api(args.arch, reduced=True)
+    api = get_api(args.arch, reduced=args.reduced, n_layers=args.layers)
     opt = adamw(constant_schedule(args.lr))
     step = jax.jit(build_train_step(api, opt, microbatches=args.microbatches))
     params = api.init(jax.random.PRNGKey(args.seed))
@@ -210,7 +216,8 @@ def run_trace(args) -> int:
         noise=args.noise,
         seed=args.seed,
         real_backend=RealBackendConfig(
-            arch=args.arch, seq_len=args.seq_len, lr=args.lr
+            arch=args.arch, reduced=args.reduced, n_layers=args.layers,
+            seq_len=args.seq_len, lr=args.lr,
         ) if real else None,
         checkpoint_dir=args.checkpoint_dir,
         faults=faults,
@@ -245,16 +252,25 @@ def run_trace(args) -> int:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--mode", default="hetero", choices=["hetero", "spmd", "trace"])
     ap.add_argument("--arch", default="olmo-1b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="build the arch's small CPU preset instead of its "
+                         "published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model's depth to this many layers "
+                         "(widths unchanged)")
     ap.add_argument("--policy", default="cannikin",
                     choices=["cannikin", "even", "ddp", "adaptdl", "lb-bsp"])
     ap.add_argument("--cluster", default="B", choices=["A", "B", "C"])
     ap.add_argument("--epochs", type=int, default=10)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--steps-per-epoch", type=int, default=8)
-    ap.add_argument("--seq-len", type=int, default=32)
+    ap.add_argument("--seq-len", type=int, default=512)
     ap.add_argument("--ref-batch", type=int, default=64)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--lr", type=float, default=0.3)

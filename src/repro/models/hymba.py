@@ -243,6 +243,17 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array, tail: Optional[jax.Ar
     return y, new_tail
 
 
+def ssm_d_block(d_inner: int) -> int:
+    """Channel block for the selective-scan kernel: the largest multiple of
+    128 lanes that divides ``d_inner`` and is at most 1024 (640 for Hymba's
+    3200), which keeps a program's VMEM near 2 MiB; the whole of
+    ``d_inner`` when no such block exists."""
+    for blk in range(min(1024, d_inner) // 128 * 128, 0, -128):
+        if d_inner % blk == 0:
+            return blk
+    return d_inner
+
+
 def _ssm_branch(
     sp: Dict[str, Any],
     x: jax.Array,
@@ -268,12 +279,12 @@ def _ssm_branch(
     elif cfg.use_kernel and h0 is None:
         from repro.kernels.ssm_scan import ssm_scan as ssm_kernel_op
 
-        d_block = di if di <= 512 else 512
         y, h = ssm_kernel_op(
             u.astype(jnp.float32), dt.astype(jnp.float32),
             b_t.astype(jnp.float32), c_t.astype(jnp.float32),
             sp["log_a"].astype(jnp.float32),
-            chunk=cfg.ssm_chunk, d_block=d_block,
+            chunk=cfg.ssm_chunk, d_block=ssm_d_block(di),
+            interpret=jax.default_backend() == "cpu",
         )
         y = y.astype(cfg.compute_dtype)
     else:

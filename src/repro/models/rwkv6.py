@@ -62,7 +62,7 @@ class RWKV6Config:
     decay_lora: int = 64
     wkv_chunk: int = 32
     use_kernel: bool = False   # route WKV through the Pallas kernel
-                               # (interpret mode on CPU; native on TPU)
+                               # (interpreted on the CPU backend only)
     param_dtype: Any = jnp.bfloat16
     compute_dtype: Any = jnp.bfloat16
     remat: bool = True
@@ -278,6 +278,7 @@ def _time_mix(
         out, s_new = wkv_kernel_op(
             r.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
             log_w, u, chunk=cfg.wkv_chunk,
+            interpret=jax.default_backend() == "cpu",
         )
         out = out.astype(cfg.compute_dtype)
     elif chunked and t > 1:

@@ -252,6 +252,255 @@ class SimBackend:
         del state
 
 
+# The step programs donate params (arg 0) and optimizer state (arg 1): the
+# update happens in place, so a step holds one copy of each, not two.
+STATE_DONATION = (0, 1)
+
+
+def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callable:
+    """The un-jitted single-device RealBackend step: vmapped per-node
+    backward, anomaly-guarded Eq. (9) aggregate, one optimizer update.
+
+    ``step(params, opt_state, tokens, labels, mask, r, lr_scale, poison)
+    -> (params, opt_state, loss, sq_i, sq_g, valid)`` over the padded
+    ``(n, b_max)`` node layout.  Module-level so a compile-only check can
+    build the exact program from abstract shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.aggregation import guard_weights
+    from repro.optim.optimizers import global_norm
+
+    def node_loss(params, tokens, labels, mask):
+        # mean over the node's real samples (pads weighted 0).
+        loss, _ = api.loss(
+            params,
+            {"tokens": tokens, "labels": labels, "weights": mask},
+        )
+        return loss
+
+    grad_fn = jax.grad(node_loss)
+
+    def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
+        # tokens/labels: (n, b_max, S); mask: (n, b_max); r/poison: (n,)
+        grads = jax.vmap(grad_fn, in_axes=(None, 0, 0, 0))(
+            params, tokens, labels, mask
+        )
+        grads = jax.tree_util.tree_map(
+            lambda g: g
+            * poison.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
+            grads,
+        )
+        sq_i = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
+        w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
+        agg = jax.tree_util.tree_map(
+            lambda g: jnp.tensordot(
+                w.astype(jnp.float32),
+                jnp.where(
+                    valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
+                ).astype(jnp.float32),
+                axes=1,
+            ).astype(g.dtype),
+            grads,
+        )
+        sq_g = global_norm(agg) ** 2
+        loss, _ = api.loss(
+            params,
+            {
+                "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
+                "labels": labels.reshape((-1,) + labels.shape[2:]),
+                "weights": mask.reshape(-1),
+            },
+        )
+        new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
+        return new_params, new_opt, loss, sq_i, sq_g, valid
+
+    return step
+
+
+def sharded_node_step_body(
+    api: Any, optimizer: Any, mesh: Any, rules: Any, n: int, *, outlier_factor: float
+) -> Callable:
+    """The un-jitted multi-device step: the node axis split over the
+    ``("nodes",)`` mesh via ``shard_map``, Eq. (9) aggregation as an
+    on-device ``psum``.
+
+    Each shard backprops its n/D nodes locally (the same vmapped
+    per-node backward), all-gathers the (n,) gradient square-norms so
+    every shard evaluates the *global* anomaly guard identically, then
+    psums its weighted local partials into the replicated Eq. (9)
+    aggregate.  The optimizer update runs outside shard_map on the
+    replicated aggregate.  The global loss is composed from per-node
+    means by their token-weight sums — algebraically equal to the vmap
+    path's full-batch forward (see models.registry._token_loss), to
+    float32 roundoff."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.aggregation import guard_weights
+    from repro.optim.optimizers import global_norm
+    from repro.train.step import node_step_specs
+
+    shard = n // int(mesh.devices.size)
+    specs = node_step_specs(rules)
+
+    def node_loss(params, tokens, labels, mask):
+        loss, _ = api.loss(
+            params,
+            {"tokens": tokens, "labels": labels, "weights": mask},
+        )
+        return loss
+
+    val_grad = jax.value_and_grad(node_loss)
+
+    def shard_body(params, tokens, labels, mask, r, poison):
+        # Local shapes: tokens/labels (n/D, b_max, S); mask (n/D, b_max);
+        # params/r/poison replicated.
+        losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
+            params, tokens, labels, mask
+        )
+        lo = jax.lax.axis_index("nodes") * shard
+        poison_local = jax.lax.dynamic_slice(poison, (lo,), (shard,))
+        grads = jax.tree_util.tree_map(
+            lambda g: g
+            * poison_local.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
+            grads,
+        )
+        sq_local = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
+        sq_i = jax.lax.all_gather(sq_local, "nodes", tiled=True)  # (n,)
+        w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
+        w_local = jax.lax.dynamic_slice(w, (lo,), (shard,))
+        valid_local = jax.lax.dynamic_slice(valid, (lo,), (shard,))
+        agg = jax.tree_util.tree_map(
+            lambda g: jax.lax.psum(
+                jnp.tensordot(
+                    w_local.astype(jnp.float32),
+                    jnp.where(
+                        valid_local.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
+                    ).astype(jnp.float32),
+                    axes=1,
+                ),
+                "nodes",
+            ).astype(g.dtype),
+            grads,
+        )
+        sq_g = global_norm(agg) ** 2
+        # Global mean loss from per-node means: node i's token-weight
+        # sum is mask_i.sum() * S (registry._token_loss broadcasts the
+        # per-sample mask over the sequence dim), and loss_i * w_sum_i
+        # recovers its loss summand; all-padding nodes contribute 0.
+        w_sum = mask.sum(axis=1) * jnp.float32(tokens.shape[-1])
+        loss_num = jax.lax.psum((losses * w_sum).sum(), "nodes")
+        loss_den = jax.lax.psum(w_sum.sum(), "nodes")
+        loss = loss_num / jnp.maximum(loss_den, 1e-9)
+        return agg, loss, sq_i, sq_g, valid
+
+    smapped = jax.shard_map(
+        shard_body,
+        mesh=mesh,
+        in_specs=(
+            P(),
+            specs["tokens"],
+            specs["labels"],
+            specs["mask"],
+            specs["replicated"],
+            specs["replicated"],
+        ),
+        out_specs=(P(), P(), P(), P(), P()),
+        # The model's scans start from unvarying carries that the body
+        # makes node-varying; every output is psum'd or all-gathered,
+        # so replication holds without the varying-axes type check.
+        check_vma=False,
+    )
+
+    def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
+        agg, loss, sq_i, sq_g, valid = smapped(
+            params, tokens, labels, mask, r, poison
+        )
+        new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
+        return new_params, new_opt, loss, sq_i, sq_g, valid
+
+    return step
+
+
+def fused_epoch_body(step_body: Callable, n: int, *, gns_decay: float) -> Callable:
+    """The un-jitted fused adaptive epoch over ``n`` nodes: lax.scan of
+    ``step_body`` over the stacked steps, the on-device Theorem-4.1 GNS EMA,
+    and the OptPerf goodput sweep + Eq. (6) argmax + water-fill partition
+    on the final state.  ``epoch_fn(params, opt_state, toks, labs, msks, r,
+    lr_scale, poison, b_vec, gns0, dc, cand, lo0, b0) -> (params,
+    opt_state, telemetry)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import optperf_jax
+    from repro.core.gns import local_estimates
+
+    decay = float(gns_decay)
+
+    def epoch_fn(
+        params, opt_state, toks, labs, msks, r, lr_scale, poison,
+        b_vec, gns0, dc, cand, lo0, b0,
+    ):
+        total = b_vec.sum()
+
+        def body(carry, xs):
+            params, opt_state, (eg, es, cnt) = carry
+            tok, lab, msk = xs
+            params, opt_state, loss, sq_i, sq_g, valid = step_body(
+                params, opt_state, tok, lab, msk, r, lr_scale, poison
+            )
+            if n >= 2:
+                g_i, s_i = local_estimates(sq_i, sq_g, b_vec, total)
+                # Theorem 4.1 minimum-variance weights, corrected
+                # closed form (same as gns.estimate_gns): the host
+                # tracker skips guarded/degenerate steps, so gate the
+                # EMA on all-valid + finite estimates.
+                w = (total - b_vec) / ((n - 1) * total)
+                g_est = (w * g_i).sum()
+                s_est = (w * s_i).sum()
+                ok = valid.all() & jnp.isfinite(g_est) & jnp.isfinite(s_est)
+            else:
+                g_est = jnp.float32(0.0)
+                s_est = jnp.float32(0.0)
+                ok = jnp.bool_(False)
+            eg = jnp.where(ok, decay * eg + (1.0 - decay) * g_est, eg)
+            es = jnp.where(ok, decay * es + (1.0 - decay) * s_est, es)
+            cnt = jnp.where(ok, cnt + 1, cnt)
+            return (params, opt_state, (eg, es, cnt)), (loss, sq_i, sq_g, valid)
+
+        (params, opt_state, (eg, es, cnt)), ys = jax.lax.scan(
+            body, (params, opt_state, gns0), (toks, labs, msks)
+        )
+        losses, sq_is, sq_gs, valids = ys
+        b_noise = jnp.where(
+            (cnt > 0) & (eg > 0.0),
+            jnp.maximum(es / jnp.where(eg > 0.0, eg, 1.0), 0.0),
+            jnp.inf,
+        )
+        t_stars, sweep_iters = optperf_jax.solve_optperf_sweep_device(
+            dc, cand, lo0
+        )
+        # Realized per-candidate OptPerf: finalize each partition and
+        # take the max node time — at small totals a clamped node's
+        # fixed floor sits above the bisected water level, and the host
+        # oracle's goodput uses the realized time.
+        parts = optperf_jax.device_partition(dc, t_stars[:, None], cand)
+        opt_perfs = optperf_jax.device_node_times(dc, parts).max(-1)
+        bn = jnp.maximum(b_noise, 0.0)
+        eff = jnp.where(jnp.isfinite(b_noise), (bn + b0) / (bn + cand), 1.0)
+        goodputs = (cand / opt_perfs) * eff
+        best = jnp.argmax(goodputs)
+        telemetry = (
+            losses, sq_is, sq_gs, valids, eg, es, cnt, b_noise,
+            opt_perfs, goodputs, best, parts[best], sweep_iters,
+        )
+        return params, opt_state, telemetry
+
+    return epoch_fn
+
+
 def _quantize(b: int, q: int = 8) -> int:
     """Quantize the padded per-node width so epoch-to-epoch repartitioning
     reuses compiled steps (recompilation hygiene; EXPERIMENTS.md §Perf)."""
@@ -317,8 +566,11 @@ class RealBackend:
             float(outlier_factor) if outlier_factor is not None
             else ANOMALY_OUTLIER_FACTOR
         )
-        self.params = api.init(jax.random.PRNGKey(seed))
-        self.opt_state = optimizer.init(self.params)
+        # One program each: initializing leaf by leaf compiles one RNG
+        # program per parameter shape, which at OLMo-1B widths took 94 s of
+        # compile on a v5e against 27 s for the whole init as one program.
+        self.params = jax.jit(api.init)(jax.random.PRNGKey(seed))
+        self.opt_state = jax.jit(optimizer.init)(self.params)
         self.gns = GNSState()
         self.gns_decay = gns_decay
         self.sim_time = 0.0
@@ -406,7 +658,7 @@ class RealBackend:
             return self._step_cache[b_max]
         import jax
 
-        fn = jax.jit(self._build_step_body())
+        fn = jax.jit(self._build_step_body(), donate_argnums=STATE_DONATION)
         self._step_cache[b_max] = fn
         return fn
 
@@ -414,164 +666,17 @@ class RealBackend:
         """The un-jitted single-device step (vmapped per-node backward).
         Shared verbatim between :meth:`_node_grad_fn` (jitted directly) and
         the fused epoch program (scanned inside one jit)."""
-        import jax
-        import jax.numpy as jnp
-
-        from repro.core.aggregation import guard_weights
-        from repro.optim.optimizers import global_norm
-
-        api, optimizer = self.api, self.optimizer
-        outlier_factor = self.outlier_factor
-
-        def node_loss(params, tokens, labels, mask):
-            # mean over the node's real samples (pads weighted 0).
-            loss, _ = api.loss(
-                params,
-                {"tokens": tokens, "labels": labels, "weights": mask},
-            )
-            return loss
-
-        grad_fn = jax.grad(node_loss)
-
-        def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
-            # tokens/labels: (n, b_max, S); mask: (n, b_max); r/poison: (n,)
-            grads = jax.vmap(grad_fn, in_axes=(None, 0, 0, 0))(
-                params, tokens, labels, mask
-            )
-            grads = jax.tree_util.tree_map(
-                lambda g: g
-                * poison.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
-                grads,
-            )
-            sq_i = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
-            w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
-            agg = jax.tree_util.tree_map(
-                lambda g: jnp.tensordot(
-                    w.astype(jnp.float32),
-                    jnp.where(
-                        valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
-                    ).astype(jnp.float32),
-                    axes=1,
-                ).astype(g.dtype),
-                grads,
-            )
-            sq_g = global_norm(agg) ** 2
-            loss, _ = api.loss(
-                params,
-                {
-                    "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
-                    "labels": labels.reshape((-1,) + labels.shape[2:]),
-                    "weights": mask.reshape(-1),
-                },
-            )
-            new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
-            return new_params, new_opt, loss, sq_i, sq_g, valid
-
-        return step
-
-    def _build_sharded_step_body(self, n: int) -> Callable:
-        """The un-jitted multi-device step: the node axis split over the
-        ``("nodes",)`` mesh via ``shard_map``, Eq. (9) aggregation as an
-        on-device ``psum``.
-
-        Each shard backprops its n/D nodes locally (the same vmapped
-        per-node backward), all-gathers the (n,) gradient square-norms so
-        every shard evaluates the *global* anomaly guard identically, then
-        psums its weighted local partials into the replicated Eq. (9)
-        aggregate.  The optimizer update runs outside shard_map on the
-        replicated aggregate.  The global loss is composed from per-node
-        means by their token-weight sums — algebraically equal to the vmap
-        path's full-batch forward (see models.registry._token_loss), to
-        float32 roundoff."""
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        from repro.core.aggregation import guard_weights
-        from repro.optim.optimizers import global_norm
-        from repro.train.step import node_step_specs
-
-        mesh, rules = self._mesh_for(n)
-        shard = n // int(mesh.devices.size)
-        specs = node_step_specs(rules)
-        api, optimizer = self.api, self.optimizer
-        outlier_factor = self.outlier_factor
-
-        def node_loss(params, tokens, labels, mask):
-            loss, _ = api.loss(
-                params,
-                {"tokens": tokens, "labels": labels, "weights": mask},
-            )
-            return loss
-
-        val_grad = jax.value_and_grad(node_loss)
-
-        def shard_body(params, tokens, labels, mask, r, poison):
-            # Local shapes: tokens/labels (n/D, b_max, S); mask (n/D, b_max);
-            # params/r/poison replicated.
-            losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
-                params, tokens, labels, mask
-            )
-            lo = jax.lax.axis_index("nodes") * shard
-            poison_local = jax.lax.dynamic_slice(poison, (lo,), (shard,))
-            grads = jax.tree_util.tree_map(
-                lambda g: g
-                * poison_local.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
-                grads,
-            )
-            sq_local = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
-            sq_i = jax.lax.all_gather(sq_local, "nodes", tiled=True)  # (n,)
-            w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
-            w_local = jax.lax.dynamic_slice(w, (lo,), (shard,))
-            valid_local = jax.lax.dynamic_slice(valid, (lo,), (shard,))
-            agg = jax.tree_util.tree_map(
-                lambda g: jax.lax.psum(
-                    jnp.tensordot(
-                        w_local.astype(jnp.float32),
-                        jnp.where(
-                            valid_local.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
-                        ).astype(jnp.float32),
-                        axes=1,
-                    ),
-                    "nodes",
-                ).astype(g.dtype),
-                grads,
-            )
-            sq_g = global_norm(agg) ** 2
-            # Global mean loss from per-node means: node i's token-weight
-            # sum is mask_i.sum() * S (registry._token_loss broadcasts the
-            # per-sample mask over the sequence dim), and loss_i * w_sum_i
-            # recovers its loss summand; all-padding nodes contribute 0.
-            w_sum = mask.sum(axis=1) * jnp.float32(tokens.shape[-1])
-            loss_num = jax.lax.psum((losses * w_sum).sum(), "nodes")
-            loss_den = jax.lax.psum(w_sum.sum(), "nodes")
-            loss = loss_num / jnp.maximum(loss_den, 1e-9)
-            return agg, loss, sq_i, sq_g, valid
-
-        smapped = shard_map(
-            shard_body,
-            mesh=mesh,
-            in_specs=(
-                P(),
-                specs["tokens"],
-                specs["labels"],
-                specs["mask"],
-                specs["replicated"],
-                specs["replicated"],
-            ),
-            out_specs=(P(), P(), P(), P(), P()),
-            check_rep=False,
+        return node_step_body(
+            self.api, self.optimizer, outlier_factor=self.outlier_factor
         )
 
-        def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
-            agg, loss, sq_i, sq_g, valid = smapped(
-                params, tokens, labels, mask, r, poison
-            )
-            new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
-            return new_params, new_opt, loss, sq_i, sq_g, valid
-
-        return step
+    def _build_sharded_step_body(self, n: int) -> Callable:
+        """The un-jitted multi-device step over the cached node mesh."""
+        mesh, rules = self._mesh_for(n)
+        return sharded_node_step_body(
+            self.api, self.optimizer, mesh, rules, n,
+            outlier_factor=self.outlier_factor,
+        )
 
     def _node_grad_fn_sharded(self, n: int) -> Callable:
         """Jitted sharded step, cached by (n, shard count); padded-width
@@ -582,7 +687,9 @@ class RealBackend:
             return self._sharded_cache[key]
         import jax
 
-        fn = jax.jit(self._build_sharded_step_body(n))
+        fn = jax.jit(
+            self._build_sharded_step_body(n), donate_argnums=STATE_DONATION
+        )
         self._sharded_cache[key] = fn
         return fn
 
@@ -697,77 +804,13 @@ class RealBackend:
         if key in self._fused_cache:
             return self._fused_cache[key]
         import jax
-        import jax.numpy as jnp
-
-        from repro.core import optperf_jax
-        from repro.core.gns import local_estimates
 
         step_body = (
             self._build_sharded_step_body(n) if use_sharded
             else self._build_step_body()
         )
-        decay = float(self.gns_decay)
-
-        def epoch_fn(
-            params, opt_state, toks, labs, msks, r, lr_scale, poison,
-            b_vec, gns0, dc, cand, lo0, b0,
-        ):
-            total = b_vec.sum()
-
-            def body(carry, xs):
-                params, opt_state, (eg, es, cnt) = carry
-                tok, lab, msk = xs
-                params, opt_state, loss, sq_i, sq_g, valid = step_body(
-                    params, opt_state, tok, lab, msk, r, lr_scale, poison
-                )
-                if n >= 2:
-                    g_i, s_i = local_estimates(sq_i, sq_g, b_vec, total)
-                    # Theorem 4.1 minimum-variance weights, corrected
-                    # closed form (same as gns.estimate_gns): the host
-                    # tracker skips guarded/degenerate steps, so gate the
-                    # EMA on all-valid + finite estimates.
-                    w = (total - b_vec) / ((n - 1) * total)
-                    g_est = (w * g_i).sum()
-                    s_est = (w * s_i).sum()
-                    ok = valid.all() & jnp.isfinite(g_est) & jnp.isfinite(s_est)
-                else:
-                    g_est = jnp.float32(0.0)
-                    s_est = jnp.float32(0.0)
-                    ok = jnp.bool_(False)
-                eg = jnp.where(ok, decay * eg + (1.0 - decay) * g_est, eg)
-                es = jnp.where(ok, decay * es + (1.0 - decay) * s_est, es)
-                cnt = jnp.where(ok, cnt + 1, cnt)
-                return (params, opt_state, (eg, es, cnt)), (loss, sq_i, sq_g, valid)
-
-            (params, opt_state, (eg, es, cnt)), ys = jax.lax.scan(
-                body, (params, opt_state, gns0), (toks, labs, msks)
-            )
-            losses, sq_is, sq_gs, valids = ys
-            b_noise = jnp.where(
-                (cnt > 0) & (eg > 0.0),
-                jnp.maximum(es / jnp.where(eg > 0.0, eg, 1.0), 0.0),
-                jnp.inf,
-            )
-            t_stars, sweep_iters = optperf_jax.solve_optperf_sweep_device(
-                dc, cand, lo0
-            )
-            # Realized per-candidate OptPerf: finalize each partition and
-            # take the max node time — at small totals a clamped node's
-            # fixed floor sits above the bisected water level, and the host
-            # oracle's goodput uses the realized time.
-            parts = optperf_jax.device_partition(dc, t_stars[:, None], cand)
-            opt_perfs = optperf_jax.device_node_times(dc, parts).max(-1)
-            bn = jnp.maximum(b_noise, 0.0)
-            eff = jnp.where(jnp.isfinite(b_noise), (bn + b0) / (bn + cand), 1.0)
-            goodputs = (cand / opt_perfs) * eff
-            best = jnp.argmax(goodputs)
-            telemetry = (
-                losses, sq_is, sq_gs, valids, eg, es, cnt, b_noise,
-                opt_perfs, goodputs, best, parts[best], sweep_iters,
-            )
-            return params, opt_state, telemetry
-
-        fn = jax.jit(epoch_fn)
+        epoch_fn = fused_epoch_body(step_body, n, gns_decay=self.gns_decay)
+        fn = jax.jit(epoch_fn, donate_argnums=STATE_DONATION)
         self._fused_cache[key] = fn
         return fn
 
@@ -932,20 +975,16 @@ class RealBackend:
         """The checkpointable pytree: everything that must survive
         preemption (params, opt-state, GNS state, stream counters).
 
-        Sharded mode gathers params/opt-state to host numpy first, so the
-        snapshot (and the PR-7 checkpoint generations built from it) is
-        byte-identical to the single-device layout and restores onto any
-        later mesh."""
-        params, opt_state = self.params, self.opt_state
-        if self.sharded:
-            import jax
+        Params/opt-state are gathered to host numpy, so the snapshot (and
+        the checkpoint generations built from it) is byte-identical across
+        layouts, restores onto any later mesh, and stays valid after the
+        next step donates the live device buffers."""
+        import jax
 
-            gather = lambda leaf: np.asarray(jax.device_get(leaf))  # noqa: E731
-            params = jax.tree_util.tree_map(gather, params)
-            opt_state = jax.tree_util.tree_map(gather, opt_state)
+        gather = lambda leaf: np.asarray(jax.device_get(leaf))  # noqa: E731
         return {
-            "params": params,
-            "opt_state": opt_state,
+            "params": jax.tree_util.tree_map(gather, self.params),
+            "opt_state": jax.tree_util.tree_map(gather, self.opt_state),
             "gns": {
                 "ema_g": np.float64(self.gns.ema_g),
                 "ema_s": np.float64(self.gns.ema_s),
@@ -958,8 +997,13 @@ class RealBackend:
         }
 
     def load_snapshot(self, state: Dict[str, Any]) -> None:
-        self.params = state["params"]
-        self.opt_state = state["opt_state"]
+        import jax
+        import jax.numpy as jnp
+
+        # Fresh device copies: the step donates its params/opt-state, which
+        # must never be buffers the caller's snapshot still holds.
+        self.params = jax.tree_util.tree_map(jnp.array, state["params"])
+        self.opt_state = jax.tree_util.tree_map(jnp.array, state["opt_state"])
         g = state["gns"]
         self.gns = GNSState(
             ema_g=float(g["ema_g"]), ema_s=float(g["ema_s"]), count=int(g["count"])
@@ -988,10 +1032,16 @@ class RealBackend:
 class RealBackendConfig:
     """Recipe the runtime uses to build a :class:`RealBackend` for each job
     whose :class:`JobSpec` names ``backend="real"`` (model/data/optimizer
-    are runtime-level concerns, not per-job spec payload)."""
+    are runtime-level concerns, not per-job spec payload).
+
+    The model is ``arch`` at its published widths.  ``n_layers`` cuts its
+    depth (to fit a chip's memory); ``reduced=True`` instead builds the
+    arch's small CPU preset, which tests and CPU examples use."""
 
     arch: str = "olmo-1b"
-    seq_len: int = 32
+    reduced: bool = False
+    n_layers: Optional[int] = None
+    seq_len: int = 512
     lr: float = 0.3
     gns_decay: float = 0.9
     sharded: bool = False
@@ -1003,7 +1053,7 @@ class RealBackendConfig:
         from repro.data.pipeline import SyntheticLM
         from repro.optim.optimizers import constant_schedule, sgd
 
-        api = get_api(self.arch, reduced=True)
+        api = get_api(self.arch, reduced=self.reduced, n_layers=self.n_layers)
         data = SyntheticLM(vocab=api.cfg.vocab, seq_len=self.seq_len, seed=seed)
         return RealBackend(
             api,
@@ -1141,6 +1191,7 @@ class EpochLoop:
         self.fused = fused
         self.epoch = 0
         self.history: List[EpochRecord] = []
+        self.last_result: Optional[ExecutionResult] = None
         self._last_measurement: Optional[StepMeasurement] = None
 
     @property
@@ -1158,6 +1209,7 @@ class EpochLoop:
             fused=self.fused,
         )
         self.epoch += 1
+        self.last_result = result
         if result.measurements:
             self._last_measurement = result.measurements[-1]
         self.history.append(record)

@@ -279,7 +279,7 @@ def _tiny_real_spec(total_batch=12, backend="real"):
 
 
 def _real_config():
-    return RealBackendConfig(arch="olmo-1b", seq_len=16, lr=0.3)
+    return RealBackendConfig(arch="olmo-1b", reduced=True, seq_len=16, lr=0.3)
 
 
 @pytest.mark.slow

@@ -48,7 +48,7 @@ def _run_loop(*, fused: bool, sharded: bool = False, n: int = 3,
               total: int = 12, candidates=(12, 24, 36)):
     spec = _spec(n, total_batch=total)
     backend = RealBackendConfig(
-        arch="olmo-1b", seq_len=16, lr=0.3, sharded=sharded
+        arch="olmo-1b", reduced=True, seq_len=16, lr=0.3, sharded=sharded
     ).build(noise=0.0, seed=0)
     backend.configure(spec, tuple(range(n)), seed=1)
     ctrl = CannikinController(
@@ -129,7 +129,7 @@ def test_sharded_backward_matches_vmap_backward():
 
     def drive(sharded: bool):
         backend = RealBackendConfig(
-            arch="olmo-1b", seq_len=16, lr=0.3, sharded=sharded
+            arch="olmo-1b", reduced=True, seq_len=16, lr=0.3, sharded=sharded
         ).build(noise=0.0, seed=0)
         backend.configure(spec, tuple(range(n)), seed=1)
         results = [backend.execute(p, steps=2) for p in plans]
@@ -183,7 +183,7 @@ def test_sharded_checkpoint_roundtrip_bit_exact(tmp_path):
     n = 4
     spec = _spec(n, total_batch=16)
     backend = RealBackendConfig(
-        arch="olmo-1b", seq_len=16, lr=0.3, sharded=True
+        arch="olmo-1b", reduced=True, seq_len=16, lr=0.3, sharded=True
     ).build(noise=0.0, seed=0)
     backend.configure(spec, tuple(range(n)), seed=1)
     backend.execute([4, 4, 4, 4], steps=2)

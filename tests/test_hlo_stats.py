@@ -7,7 +7,7 @@ import pytest
 
 pytestmark = pytest.mark.slow  # JAX-compiling; excluded from the fast lane
 
-from repro.launch.hlo_stats import analyze_hlo, raw_cost_analysis
+from repro.launch.hlo_stats import analyze_hlo
 
 
 def _compile(f, *args):
@@ -32,8 +32,7 @@ def test_scan_trip_correction():
     x = jnp.ones((128, 128))
     w = jnp.ones((128, 128))
     comp = _compile(f, x, w)
-    # raw_cost_analysis: jax < 0.5 returns cost_analysis() as a 1-elem list.
-    raw = raw_cost_analysis(comp)["flops"]
+    raw = comp.cost_analysis()["flops"]
     st = analyze_hlo(comp.as_text())
     expected = 2 * 128**3 * 10
     # XLA counts the while body once...

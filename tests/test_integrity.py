@@ -470,7 +470,7 @@ def _tiny_real_spec():
 
 
 def _real_config():
-    return RealBackendConfig(arch="olmo-1b", seq_len=16, lr=0.3)
+    return RealBackendConfig(arch="olmo-1b", reduced=True, seq_len=16, lr=0.3)
 
 
 def _leaves(tree):

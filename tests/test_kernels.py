@@ -32,7 +32,8 @@ def test_flash_attention_sweep(b, s, t, h, kv, d, causal, window, dtype):
     q = jax.random.normal(ks[0], (b, s, h, d), dtype)
     k = jax.random.normal(ks[1], (b, t, kv, d), dtype)
     v = jax.random.normal(ks[2], (b, t, kv, d), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64,
+                          interpret=True)
     rep = h // kv
     kr = jnp.repeat(k, rep, axis=2)
     vr = jnp.repeat(v, rep, axis=2)
@@ -57,7 +58,7 @@ def test_wkv_sweep(b, t, h, k, chunk, dtype):
     v = (jax.random.normal(ks[2], (b, t, h, k)) * 0.5).astype(dtype)
     lw = (-jnp.exp(jax.random.normal(ks[3], (b, t, h, k)))).astype(jnp.float32)
     u = jax.random.normal(ks[4], (h, k), jnp.float32) * 0.2
-    out, s = wkv(r, kk, v, lw, u, chunk=chunk)
+    out, s = wkv(r, kk, v, lw, u, chunk=chunk, interpret=True)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, x.shape[-1])
     uu = jnp.broadcast_to(u[None], (b, h, k)).reshape(b * h, k)
     oref, sref = wkv_ref(
@@ -88,7 +89,8 @@ def test_ssm_scan_sweep(b, t, d, n, chunk, d_block, dtype):
     bt = jax.random.normal(ks[2], (b, t, n), dtype)
     ct = jax.random.normal(ks[3], (b, t, n), dtype)
     la = (jax.random.normal(ks[4], (d, n)) * 0.5).astype(jnp.float32)
-    y, h = ssm_scan(u, dt, bt, ct, la, chunk=chunk, d_block=d_block)
+    y, h = ssm_scan(u, dt, bt, ct, la, chunk=chunk, d_block=d_block,
+                    interpret=True)
     yr, hr = ssm_ref(
         u.astype(jnp.float32), dt.astype(jnp.float32),
         bt.astype(jnp.float32), ct.astype(jnp.float32), la,
@@ -103,13 +105,14 @@ def test_kernels_jit_compatible():
     """ops.py wrappers must be jittable (the production path)."""
     rng = jax.random.PRNGKey(0)
     q = jax.random.normal(rng, (1, 128, 2, 64))
-    out = jax.jit(lambda q: flash_attention(q, q, q))(q)
+    out = jax.jit(lambda q: flash_attention(q, q, q, interpret=True))(q)
     assert out.shape == q.shape
 
 
 def test_kernel_model_paths_match_jnp():
-    """use_kernel=True routes RWKV6/Hymba through the Pallas kernels; the
-    model logits must match the jnp path (first-class kernel integration)."""
+    """use_kernel=True routes RWKV6/Hymba through the Pallas kernels
+    (interpreted, since the backend here is the CPU); the model logits must
+    match the jnp path (first-class kernel integration)."""
     import dataclasses
 
     from repro.configs import get_api

@@ -7,15 +7,13 @@ import pytest
 pytestmark = pytest.mark.slow  # JAX-compiling; excluded from the fast lane
 
 jax = pytest.importorskip("jax")
-from jax.experimental import enable_x64  # noqa: E402
 
-from repro.core.goodput import BatchSizeSelector  # noqa: E402
+from repro.core.goodput import BatchSizeSelector, _array_sweep_solver  # noqa: E402
 from repro.core.optperf import (  # noqa: E402
     solve_optperf_batch,
     solve_optperf_waterfill,
 )
 from repro.core.optperf_jax import (  # noqa: E402
-    HAS_JAX,
     device_coeffs,
     solve_optperf_batch_jax,
 )
@@ -40,8 +38,11 @@ def random_model(rng: np.random.Generator, n: int) -> ClusterPerfModel:
     return ClusterPerfModel(nodes=nodes, comm=comm)
 
 
-def test_has_jax_in_test_image():
-    assert HAS_JAX
+def test_array_sweep_solver_picks_engine():
+    """engine="jax" always selects the on-device sweep (no silent NumPy
+    fallback); any other engine name selects the NumPy batched sweep."""
+    assert _array_sweep_solver("jax") is solve_optperf_batch_jax
+    assert _array_sweep_solver("batched") is solve_optperf_batch
 
 
 @pytest.mark.parametrize("n", [2, 16, 64, 256])
@@ -49,7 +50,7 @@ def test_jax_vs_batched_vs_scalar_equivalence_x64(n):
     """Acceptance: under x64 the on-device sweep, the NumPy batched engine,
     and the scalar water-fill oracle agree within 1e-6 relative opt_perf
     (they actually agree to ~1e-9) for n in {2, 16, 64, 256}."""
-    with enable_x64():
+    with jax.enable_x64(True):
         for seed in range(5):
             rng = np.random.default_rng(1000 * n + seed)
             model = random_model(rng, n)
@@ -110,7 +111,7 @@ def test_device_coeffs_cached_and_dtype_keyed():
     assert a is b                       # cached per (model, dtype)
     assert a.alphas.shape == (6,)
     assert a.alphas.dtype == np.float32 or a.alphas.dtype == np.float64
-    with enable_x64():
+    with jax.enable_x64(True):
         c = device_coeffs(model)
     assert c.alphas.dtype == np.float64  # x64 flip gets its own cache entry
     np.testing.assert_allclose(

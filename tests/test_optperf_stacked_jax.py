@@ -14,7 +14,6 @@ from repro.core.optperf import (  # noqa: E402
     solve_optperf_waterfill,
 )
 from repro.core.optperf_jax import (  # noqa: E402
-    HAS_JAX,
     solve_optperf_stacked_jax,
     stacked_device_coeffs,
 )
@@ -59,8 +58,13 @@ def ragged_stack(rng: np.random.Generator, rows: int):
     return models, StackedClusterModel.from_models(models), totals
 
 
-def test_has_jax_in_test_image():
-    assert HAS_JAX
+def test_stacked_solver_picks_engine():
+    """engine="jax" always selects the stacked on-device kernel (no silent
+    NumPy fallback); any other engine name selects the NumPy stacked path."""
+    from repro.core.scheduler import _stacked_solver
+
+    assert _stacked_solver("jax") is solve_optperf_stacked_jax
+    assert _stacked_solver("batched") is solve_optperf_stacked
 
 
 def test_stacked_jax_parity_100_seeded_ragged_clusters():

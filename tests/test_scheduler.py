@@ -473,8 +473,6 @@ def test_membership_change_evicts_device_coeff_export(change):
     pytest.importorskip("jax")
     from repro.core import optperf_jax
 
-    if not optperf_jax.HAS_JAX:
-        pytest.skip("jax unavailable")
     profiles, comm = cluster_B()
     sim = SimulatedCluster(profiles, comm, noise=0.005, seed=0)
     ctrl = CannikinController(

@@ -95,7 +95,7 @@ def loss(params, batch):
 
 g1 = jax.grad(loss)(params, {"tokens": tokens, "labels": labels, "weights": weights})
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 bs = NamedSharding(mesh, P("data"))
 batch = {
     "tokens": jax.device_put(tokens, NamedSharding(mesh, P("data", None))),
@@ -117,18 +117,16 @@ print("SPMD-OK")
     assert "SPMD-OK" in out.stdout, out.stderr[-2000:]
 
 
-def test_enter_mesh_portable_context():
-    """enter_mesh works on jax versions without jax.set_mesh / use_mesh:
-    inside the context, bare-PartitionSpec sharding constraints resolve
-    against the active mesh."""
+def test_set_mesh_resolves_bare_specs_on_auto_mesh():
+    """Under ``jax.set_mesh`` with Auto axes (how the dry-run and the
+    production mesh are built), bare-PartitionSpec sharding constraints
+    resolve against the active mesh."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import enter_mesh
-
-    mesh = jax.make_mesh((1,), ("data",))
-    with enter_mesh(mesh):
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
+    with jax.set_mesh(mesh):
         y = jax.jit(
             lambda x: jax.lax.with_sharding_constraint(x, P("data"))
         )(jnp.arange(8.0))
@@ -149,7 +147,8 @@ from repro.optim import adamw, constant_schedule
 from repro.train.step import build_train_step
 
 api = get_api("llama3-8b", reduced=True)
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = MeshRules(mesh_axes={"data": 4, "model": 4}, batch_axes=("data",))
 opt = adamw(constant_schedule(1e-3))
 step = build_train_step(api, opt, microbatches=2, with_metrics=False)
@@ -162,15 +161,13 @@ named = lambda t: jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), t,
 bspecs = {k: NamedSharding(mesh, rules.batch_spec(extra_dims=len(v.shape)-1))
           for k, v in batch_sds.items()}
 from repro.launch.dryrun import _opt_specs
-from repro.launch.hlo_stats import raw_cost_analysis
-from repro.launch.mesh import enter_mesh
 ospecs = _opt_specs(opt_sds, pspecs)
-with enter_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(
         lambda p, o, b: step(p, o, b),
         in_shardings=(named(pspecs), named(ospecs), bspecs),
     ).lower(params_sds, opt_sds, batch_sds).compile()
-print("DRYRUN-OK", raw_cost_analysis(compiled)["flops"] > 0)
+print("DRYRUN-OK", compiled.cost_analysis()["flops"] > 0)
 """
     env = dict(os.environ, PYTHONPATH="src")
     out = subprocess.run(
