@@ -47,6 +47,7 @@ from typing import (
 )
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.aggregation import ratios
 from repro.core.controller import (
@@ -58,7 +59,7 @@ from repro.core.controller import (
 from repro.core.gns import GNSState, estimate_gns, gns_update
 from repro.core.scheduler import JobSpec
 from repro.core.simulator import NodeProfile, SimulatedCluster, StepMeasurement
-from repro.runtime.transfers import TransferCounter
+from repro.runtime.telemetry import Telemetry
 
 __all__ = [
     "GradObservation",
@@ -256,6 +257,14 @@ class SimBackend:
 # update happens in place, so a step holds one copy of each, not two.
 STATE_DONATION = (0, 1)
 
+# Named scopes of the step's phases: each device op's ``op_name`` metadata
+# carries one, so a profiler trace splits the step's device time by phase.
+SCOPE_BACKWARD = "node_backward"        # the per-node backward
+SCOPE_AGGREGATE = "eq9_aggregate"       # poison, sq-norms, guard, Eq. 9, |g|^2
+SCOPE_LOSS = "loss_forward"             # the step's reported loss
+SCOPE_UPDATE = "optimizer_update"
+STEP_SCOPES = (SCOPE_BACKWARD, SCOPE_AGGREGATE, SCOPE_LOSS, SCOPE_UPDATE)
+
 
 def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callable:
     """The un-jitted single-device RealBackend step: vmapped per-node
@@ -264,7 +273,8 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
     ``step(params, opt_state, tokens, labels, mask, r, lr_scale, poison)
     -> (params, opt_state, loss, sq_i, sq_g, valid)`` over the padded
     ``(n, b_max)`` node layout.  Module-level so a compile-only check can
-    build the exact program from abstract shapes."""
+    build the exact program from abstract shapes.  Its phases run under
+    the named scopes of ``STEP_SCOPES``."""
     import jax
     import jax.numpy as jnp
 
@@ -283,36 +293,40 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
 
     def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
         # tokens/labels: (n, b_max, S); mask: (n, b_max); r/poison: (n,)
-        grads = jax.vmap(grad_fn, in_axes=(None, 0, 0, 0))(
-            params, tokens, labels, mask
-        )
-        grads = jax.tree_util.tree_map(
-            lambda g: g
-            * poison.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
-            grads,
-        )
-        sq_i = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
-        w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
-        agg = jax.tree_util.tree_map(
-            lambda g: jnp.tensordot(
-                w.astype(jnp.float32),
-                jnp.where(
-                    valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
-                ).astype(jnp.float32),
-                axes=1,
-            ).astype(g.dtype),
-            grads,
-        )
-        sq_g = global_norm(agg) ** 2
-        loss, _ = api.loss(
-            params,
-            {
-                "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
-                "labels": labels.reshape((-1,) + labels.shape[2:]),
-                "weights": mask.reshape(-1),
-            },
-        )
-        new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
+        with jax.named_scope(SCOPE_BACKWARD):
+            grads = jax.vmap(grad_fn, in_axes=(None, 0, 0, 0))(
+                params, tokens, labels, mask
+            )
+        with jax.named_scope(SCOPE_AGGREGATE):
+            grads = jax.tree_util.tree_map(
+                lambda g: g
+                * poison.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
+                grads,
+            )
+            sq_i = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
+            w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
+            agg = jax.tree_util.tree_map(
+                lambda g: jnp.tensordot(
+                    w.astype(jnp.float32),
+                    jnp.where(
+                        valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
+                    ).astype(jnp.float32),
+                    axes=1,
+                ).astype(g.dtype),
+                grads,
+            )
+            sq_g = global_norm(agg) ** 2
+        with jax.named_scope(SCOPE_LOSS):
+            loss, _ = api.loss(
+                params,
+                {
+                    "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
+                    "labels": labels.reshape((-1,) + labels.shape[2:]),
+                    "weights": mask.reshape(-1),
+                },
+            )
+        with jax.named_scope(SCOPE_UPDATE):
+            new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
         return new_params, new_opt, loss, sq_i, sq_g, valid
 
     return step
@@ -333,7 +347,8 @@ def sharded_node_step_body(
     replicated aggregate.  The global loss is composed from per-node
     means by their token-weight sums — algebraically equal to the vmap
     path's full-batch forward (see models.registry._token_loss), to
-    float32 roundoff."""
+    float32 roundoff.  The phases carry the scopes of ``STEP_SCOPES``; the
+    composed loss runs under ``SCOPE_LOSS``."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -357,43 +372,46 @@ def sharded_node_step_body(
     def shard_body(params, tokens, labels, mask, r, poison):
         # Local shapes: tokens/labels (n/D, b_max, S); mask (n/D, b_max);
         # params/r/poison replicated.
-        losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
-            params, tokens, labels, mask
-        )
-        lo = jax.lax.axis_index("nodes") * shard
-        poison_local = jax.lax.dynamic_slice(poison, (lo,), (shard,))
-        grads = jax.tree_util.tree_map(
-            lambda g: g
-            * poison_local.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
-            grads,
-        )
-        sq_local = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
-        sq_i = jax.lax.all_gather(sq_local, "nodes", tiled=True)  # (n,)
-        w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
-        w_local = jax.lax.dynamic_slice(w, (lo,), (shard,))
-        valid_local = jax.lax.dynamic_slice(valid, (lo,), (shard,))
-        agg = jax.tree_util.tree_map(
-            lambda g: jax.lax.psum(
-                jnp.tensordot(
-                    w_local.astype(jnp.float32),
-                    jnp.where(
-                        valid_local.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
-                    ).astype(jnp.float32),
-                    axes=1,
-                ),
-                "nodes",
-            ).astype(g.dtype),
-            grads,
-        )
-        sq_g = global_norm(agg) ** 2
-        # Global mean loss from per-node means: node i's token-weight
-        # sum is mask_i.sum() * S (registry._token_loss broadcasts the
-        # per-sample mask over the sequence dim), and loss_i * w_sum_i
-        # recovers its loss summand; all-padding nodes contribute 0.
-        w_sum = mask.sum(axis=1) * jnp.float32(tokens.shape[-1])
-        loss_num = jax.lax.psum((losses * w_sum).sum(), "nodes")
-        loss_den = jax.lax.psum(w_sum.sum(), "nodes")
-        loss = loss_num / jnp.maximum(loss_den, 1e-9)
+        with jax.named_scope(SCOPE_BACKWARD):
+            losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
+                params, tokens, labels, mask
+            )
+        with jax.named_scope(SCOPE_AGGREGATE):
+            lo = jax.lax.axis_index("nodes") * shard
+            poison_local = jax.lax.dynamic_slice(poison, (lo,), (shard,))
+            grads = jax.tree_util.tree_map(
+                lambda g: g
+                * poison_local.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
+                grads,
+            )
+            sq_local = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
+            sq_i = jax.lax.all_gather(sq_local, "nodes", tiled=True)  # (n,)
+            w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
+            w_local = jax.lax.dynamic_slice(w, (lo,), (shard,))
+            valid_local = jax.lax.dynamic_slice(valid, (lo,), (shard,))
+            agg = jax.tree_util.tree_map(
+                lambda g: jax.lax.psum(
+                    jnp.tensordot(
+                        w_local.astype(jnp.float32),
+                        jnp.where(
+                            valid_local.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
+                        ).astype(jnp.float32),
+                        axes=1,
+                    ),
+                    "nodes",
+                ).astype(g.dtype),
+                grads,
+            )
+            sq_g = global_norm(agg) ** 2
+        with jax.named_scope(SCOPE_LOSS):
+            # Global mean loss from per-node means: node i's token-weight
+            # sum is mask_i.sum() * S (registry._token_loss broadcasts the
+            # per-sample mask over the sequence dim), and loss_i * w_sum_i
+            # recovers its loss summand; all-padding nodes contribute 0.
+            w_sum = mask.sum(axis=1) * jnp.float32(tokens.shape[-1])
+            loss_num = jax.lax.psum((losses * w_sum).sum(), "nodes")
+            loss_den = jax.lax.psum(w_sum.sum(), "nodes")
+            loss = loss_num / jnp.maximum(loss_den, 1e-9)
         return agg, loss, sq_i, sq_g, valid
 
     smapped = jax.shard_map(
@@ -418,7 +436,8 @@ def sharded_node_step_body(
         agg, loss, sq_i, sq_g, valid = smapped(
             params, tokens, labels, mask, r, poison
         )
-        new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
+        with jax.named_scope(SCOPE_UPDATE):
+            new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
         return new_params, new_opt, loss, sq_i, sq_g, valid
 
     return step
@@ -534,6 +553,14 @@ class RealBackend:
     with or without an injector), and its ``perturb`` post-transforms the
     timing measurement stream exactly as it does for :class:`SimBackend`
     (timing faults hit the real path too).
+
+    **Observability.**  ``telemetry`` (:class:`~repro.runtime.telemetry.
+    Telemetry`) counts steps, useful and padded rows, every array shipped
+    and pulled with its bytes, and step programs built.  Each step of
+    :meth:`execute` runs in a ``repro.step`` profiler span (``step_num``),
+    its phases in ``repro.step.input``, ``.put``, ``.dispatch``, ``.pull``
+    (the host's wait for the device) and ``.gns``; :meth:`execute_fused`
+    uses ``repro.fused`` and the same phase names under it.
     """
 
     kind = "real"
@@ -577,7 +604,7 @@ class RealBackend:
         self.steps_done = 0
         self.anomalous_steps = 0       # steps with >= 1 excluded node (lifetime)
         self.sharded = bool(sharded)
-        self.transfers = TransferCounter()
+        self.telemetry = Telemetry()
         self._step_cache: Dict[int, Callable] = {}
         self._sharded_cache: Dict[Tuple[int, int], Callable] = {}
         self._fused_cache: Dict[Tuple[int, int], Callable] = {}
@@ -660,6 +687,7 @@ class RealBackend:
 
         fn = jax.jit(self._build_step_body(), donate_argnums=STATE_DONATION)
         self._step_cache[b_max] = fn
+        self.telemetry.programs_built += 1
         return fn
 
     def _build_step_body(self) -> Callable:
@@ -691,6 +719,7 @@ class RealBackend:
             self._build_sharded_step_body(n), donate_argnums=STATE_DONATION
         )
         self._sharded_cache[key] = fn
+        self.telemetry.programs_built += 1
         return fn
 
     def execute(
@@ -706,7 +735,8 @@ class RealBackend:
         b_arr = np.asarray(batches, np.int64)
         b_max = _quantize(int(b_arr.max()))
         n = len(batches)
-        r = jnp.asarray(ratios(batches), jnp.float32)
+        tel = self.telemetry
+        r = tel.put(np.asarray(ratios(batches), np.float32), jnp.asarray)
         use_sharded = self.sharded and n > 0
         if use_sharded:
             step_fn = self._node_grad_fn_sharded(n)
@@ -714,59 +744,61 @@ class RealBackend:
         else:
             step_fn = self._node_grad_fn(b_max)
             place = jnp.asarray
-        self.transfers.count_h2d(2)  # r + poison, shipped once per epoch
 
         node_ids = self._node_ids if len(self._node_ids) == n else tuple(range(n))
         if self.injector is not None:
             poison_np = self.injector.poison_factors(node_ids)
         else:
             poison_np = np.ones(n, np.float32)
-        poison = jnp.asarray(poison_np, jnp.float32)
+        poison = tel.put(np.asarray(poison_np, np.float32), jnp.asarray)
         anomaly_counts = np.zeros(n, np.int64)
 
         losses: List[float] = []
         grad_obs: List[GradObservation] = []
         for _ in range(steps):
-            raw = self.data.batch(self.steps_done, int(b_arr.sum()))
-            self.steps_done += 1
-            padded, _ = HeteroBatchPartitioner.padded(raw, batches)
-            seq = padded["tokens"].shape[-1]
-            tok = np.zeros((n, b_max, seq), np.int32)
-            lab = np.zeros((n, b_max, seq), np.int32)
-            msk = np.zeros((n, b_max), np.float32)
-            w = padded["tokens"].shape[1]
-            tok[:, :w], lab[:, :w] = padded["tokens"], padded["labels"]
-            for i, b in enumerate(batches):
-                msk[i, :b] = 1.0
-            # 4 h2d per step (tok/lab/msk + lr scalar), 4 d2h pulls below —
-            # the per-step host round-trips the fused path collapses.
-            self.transfers.count_h2d(4)
-            self.transfers.count_d2h(4)
-            self.params, self.opt_state, loss, sq_i, sq_g, valid = step_fn(
-                self.params,
-                self.opt_state,
-                place(tok),
-                place(lab),
-                place(msk),
-                r,
-                jnp.float32(lr_scale),
-                poison,
-            )
-            valid_np = np.asarray(valid, bool)
-            anomaly_counts += ~valid_np
-            self.anomalous_steps += int(not valid_np.all())
-            losses.append(float(loss))
-            obs = GradObservation(
-                local_sqnorms=tuple(float(x) for x in np.asarray(sq_i)),
-                global_sqnorm=float(sq_g),
-                batches=tuple(batches),
-                valid=tuple(bool(v) for v in valid_np),
-            )
-            grad_obs.append(obs)
-            if obs.all_valid:
-                # Poisoned steps carry non-finite/outlier sq-norms: feeding
-                # them to the Theorem-4.1 tracker would corrupt b_noise.
-                self._track_gns(obs)
+            step_num = self.steps_done
+            with StepTraceAnnotation("repro.step", step_num=step_num):
+                with TraceAnnotation("repro.step.input", step_num=step_num):
+                    raw = self.data.batch(self.steps_done, int(b_arr.sum()))
+                    self.steps_done += 1
+                    padded, _ = HeteroBatchPartitioner.padded(raw, batches)
+                    seq = padded["tokens"].shape[-1]
+                    tok = np.zeros((n, b_max, seq), np.int32)
+                    lab = np.zeros((n, b_max, seq), np.int32)
+                    msk = np.zeros((n, b_max), np.float32)
+                    w = padded["tokens"].shape[1]
+                    tok[:, :w], lab[:, :w] = padded["tokens"], padded["labels"]
+                    for i, b in enumerate(batches):
+                        msk[i, :b] = 1.0
+                tel.count_steps(1, int(b_arr.sum()), n * b_max)
+                with TraceAnnotation("repro.step.put", step_num=step_num):
+                    args = tuple(tel.put(a, place) for a in (tok, lab, msk))
+                    lr = tel.put(np.float32(lr_scale), jnp.asarray)
+                with TraceAnnotation("repro.step.dispatch", step_num=step_num):
+                    self.params, self.opt_state, loss, sq_i, sq_g, valid = step_fn(
+                        self.params, self.opt_state, *args, r, lr, poison
+                    )
+                # Blocks until the device has finished the step.
+                with TraceAnnotation("repro.step.pull", step_num=step_num):
+                    valid_np = np.asarray(tel.pull(valid), bool)
+                    loss_np = tel.pull(loss)
+                    sq_i_np = tel.pull(sq_i)
+                    sq_g_np = tel.pull(sq_g)
+                anomaly_counts += ~valid_np
+                self.anomalous_steps += int(not valid_np.all())
+                losses.append(float(loss_np))
+                obs = GradObservation(
+                    local_sqnorms=tuple(float(x) for x in sq_i_np),
+                    global_sqnorm=float(sq_g_np),
+                    batches=tuple(batches),
+                    valid=tuple(bool(v) for v in valid_np),
+                )
+                grad_obs.append(obs)
+                if obs.all_valid:
+                    # Poisoned steps carry non-finite/outlier sq-norms: feeding
+                    # them to the Theorem-4.1 tracker would corrupt b_noise.
+                    with TraceAnnotation("repro.step.gns", step_num=step_num):
+                        self._track_gns(obs)
 
         epoch_seconds, measurements = self.cluster.run_epoch(batches, steps)
         measurements = list(measurements)
@@ -812,6 +844,7 @@ class RealBackend:
         epoch_fn = fused_epoch_body(step_body, n, gns_decay=self.gns_decay)
         fn = jax.jit(epoch_fn, donate_argnums=STATE_DONATION)
         self._fused_cache[key] = fn
+        self.telemetry.programs_built += 1
         return fn
 
     def execute_fused(
@@ -835,7 +868,6 @@ class RealBackend:
         """
         if self.cluster is None:
             raise RuntimeError("RealBackend not configured with a cluster")
-        import jax
         import jax.numpy as jnp
 
         from repro.data.pipeline import HeteroBatchPartitioner
@@ -853,60 +885,65 @@ class RealBackend:
             poison_np = np.ones(n, np.float32)
 
         # Stage the whole epoch's data host-side, ship it once.
-        toks = labs = msks = None
-        for s in range(steps):
-            raw = self.data.batch(self.steps_done, int(b_arr.sum()))
-            self.steps_done += 1
-            padded, _ = HeteroBatchPartitioner.padded(raw, batches)
-            seq = padded["tokens"].shape[-1]
-            if toks is None:
-                toks = np.zeros((steps, n, b_max, seq), np.int32)
-                labs = np.zeros((steps, n, b_max, seq), np.int32)
-                msks = np.zeros((steps, n, b_max), np.float32)
-                for i, b in enumerate(batches):
-                    msks[:, i, :b] = 1.0
-            w = padded["tokens"].shape[1]
-            toks[s, :, :w], labs[s, :, :w] = padded["tokens"], padded["labels"]
+        tel = self.telemetry
+        step0 = self.steps_done
+        with TraceAnnotation("repro.fused", step_num=step0, steps=steps):
+            with TraceAnnotation("repro.fused.input", step_num=step0):
+                toks = labs = msks = None
+                for s in range(steps):
+                    raw = self.data.batch(self.steps_done, int(b_arr.sum()))
+                    self.steps_done += 1
+                    padded, _ = HeteroBatchPartitioner.padded(raw, batches)
+                    seq = padded["tokens"].shape[-1]
+                    if toks is None:
+                        toks = np.zeros((steps, n, b_max, seq), np.int32)
+                        labs = np.zeros((steps, n, b_max, seq), np.int32)
+                        msks = np.zeros((steps, n, b_max), np.float32)
+                        for i, b in enumerate(batches):
+                            msks[:, i, :b] = 1.0
+                    w = padded["tokens"].shape[1]
+                    toks[s, :, :w], labs[s, :, :w] = padded["tokens"], padded["labels"]
+            tel.count_steps(steps, int(b_arr.sum()), n * b_max)
 
-        if use_sharded:
-            place = self._node_placer(n, stacked=True)
-            toks_d, labs_d, msks_d = place(toks), place(labs), place(msks)
-        else:
-            toks_d, labs_d, msks_d = (
-                jnp.asarray(toks), jnp.asarray(labs), jnp.asarray(msks)
-            )
-        gns0 = (
-            jnp.float32(self.gns.ema_g),
-            jnp.float32(self.gns.ema_s),
-            jnp.int32(self.gns.count),
-        )
-        # 3 stacked arrays + r/poison/lr/b_vec + 3 GNS carry scalars +
-        # lo0/b0 — every host value entering the program this epoch.
-        self.transfers.count_h2d(12)
+            with TraceAnnotation("repro.fused.put", step_num=step0):
+                place = (
+                    self._node_placer(n, stacked=True) if use_sharded else jnp.asarray
+                )
+                toks_d, labs_d, msks_d = (tel.put(a, place) for a in (toks, labs, msks))
+                gns0 = (
+                    tel.put(np.float32(self.gns.ema_g), jnp.asarray),
+                    tel.put(np.float32(self.gns.ema_s), jnp.asarray),
+                    tel.put(np.int32(self.gns.count), jnp.asarray),
+                )
+                r = tel.put(np.asarray(ratios(batches), np.float32), jnp.asarray)
+                lr = tel.put(np.float32(lr_scale), jnp.asarray)
+                poison = tel.put(np.asarray(poison_np, np.float32), jnp.asarray)
+                b_vec = tel.put(np.asarray(b_arr, np.float32), jnp.asarray)
 
-        fused_fn = self._fused_epoch_fn(n)
-        self.params, self.opt_state, telemetry = fused_fn(
-            self.params,
-            self.opt_state,
-            toks_d,
-            labs_d,
-            msks_d,
-            jnp.asarray(ratios(batches), jnp.float32),
-            jnp.float32(lr_scale),
-            jnp.asarray(poison_np, jnp.float32),
-            jnp.asarray(b_arr, jnp.float32),
-            gns0,
-            ctx.coeffs,
-            ctx.candidates,
-            float(ctx.lo0),
-            float(ctx.ref_batch),
-        )
-        pulled = jax.device_get(telemetry)
-        self.transfers.count_d2h(len(jax.tree_util.tree_leaves(telemetry)))
-        (
-            losses_a, sq_is, sq_gs, valids, eg, es, cnt, b_noise_dev,
-            t_stars, goodputs, best, best_batches, sweep_iters,
-        ) = pulled
+            fused_fn = self._fused_epoch_fn(n)
+            with TraceAnnotation("repro.fused.dispatch", step_num=step0):
+                self.params, self.opt_state, telemetry = fused_fn(
+                    self.params,
+                    self.opt_state,
+                    toks_d,
+                    labs_d,
+                    msks_d,
+                    r,
+                    lr,
+                    poison,
+                    b_vec,
+                    gns0,
+                    ctx.coeffs,
+                    ctx.candidates,
+                    float(ctx.lo0),
+                    float(ctx.ref_batch),
+                )
+            # Blocks until the device has finished the epoch.
+            with TraceAnnotation("repro.fused.pull", step_num=step0):
+                (
+                    losses_a, sq_is, sq_gs, valids, eg, es, cnt, b_noise_dev,
+                    t_stars, goodputs, best, best_batches, sweep_iters,
+                ) = tel.pull(telemetry)
 
         self.gns = GNSState(ema_g=float(eg), ema_s=float(es), count=int(cnt))
         anomaly_counts = (~np.asarray(valids, bool)).sum(axis=0)
@@ -1117,41 +1154,52 @@ def run_backend_epoch(
     program stages the next one.  Whenever the controller cannot supply a
     fused context (bootstrap, jax missing, certification failure) the cycle
     is exactly the two-program path — bit-compatible fallback.
+
+    The cycle runs in a ``repro.epoch`` profiler span with ``epoch_index``
+    as its argument, its phases in ``repro.epoch.plan``, ``.execute``,
+    ``.observe`` and (fused) ``.stage``.
     """
-    if isinstance(policy, CannikinController):
-        plan = policy.plan_epoch(prefer_fused=fused)
-        epoch = plan.epoch
-        batches = list(plan.batches)
-        total = plan.total_batch
-        lr_scale = plan.lr_scale
-        predicted = plan.predicted_batch_time
-        phase = plan.phase
-    else:
-        plan = None
-        epoch = epoch_index
-        total = getattr(policy, "total_batch", None) or fixed_total or 64
-        batches = policy.partition(total, epoch, last_measurement)
-        lr_scale, predicted, phase = 1.0, None, policy.name
-    fused_ctx = None
-    if (
-        fused
-        and isinstance(policy, CannikinController)
-        and hasattr(backend, "execute_fused")
-    ):
-        fused_ctx = policy.fused_context()
-    if fused_ctx is not None:
-        result, proposal = backend.execute_fused(
-            batches, steps, lr_scale=lr_scale, ctx=fused_ctx
-        )
-    else:
-        result, proposal = backend.execute(batches, steps, lr_scale=lr_scale), None
-    if isinstance(policy, CannikinController):
-        policy.observe_execution(result)
-        if fused_ctx is not None and proposal is not None:
-            policy.stage_fused_proposal(fused_ctx, proposal)
-        b_noise = policy.gns.b_noise
-    else:
-        b_noise = result.b_noise
+    with TraceAnnotation("repro.epoch", epoch=epoch_index):
+        if isinstance(policy, CannikinController):
+            with TraceAnnotation("repro.epoch.plan", epoch=epoch_index):
+                plan = policy.plan_epoch(prefer_fused=fused)
+            epoch = plan.epoch
+            batches = list(plan.batches)
+            total = plan.total_batch
+            lr_scale = plan.lr_scale
+            predicted = plan.predicted_batch_time
+            phase = plan.phase
+        else:
+            plan = None
+            epoch = epoch_index
+            total = getattr(policy, "total_batch", None) or fixed_total or 64
+            with TraceAnnotation("repro.epoch.plan", epoch=epoch_index):
+                batches = policy.partition(total, epoch, last_measurement)
+            lr_scale, predicted, phase = 1.0, None, policy.name
+        fused_ctx = None
+        if (
+            fused
+            and isinstance(policy, CannikinController)
+            and hasattr(backend, "execute_fused")
+        ):
+            fused_ctx = policy.fused_context()
+        with TraceAnnotation("repro.epoch.execute", epoch=epoch_index):
+            if fused_ctx is not None:
+                result, proposal = backend.execute_fused(
+                    batches, steps, lr_scale=lr_scale, ctx=fused_ctx
+                )
+            else:
+                result = backend.execute(batches, steps, lr_scale=lr_scale)
+                proposal = None
+        if isinstance(policy, CannikinController):
+            with TraceAnnotation("repro.epoch.observe", epoch=epoch_index):
+                policy.observe_execution(result)
+            if fused_ctx is not None and proposal is not None:
+                with TraceAnnotation("repro.epoch.stage", epoch=epoch_index):
+                    policy.stage_fused_proposal(fused_ctx, proposal)
+            b_noise = policy.gns.b_noise
+        else:
+            b_noise = result.b_noise
     record = EpochRecord(
         epoch=epoch,
         backend=getattr(backend, "kind", "?"),

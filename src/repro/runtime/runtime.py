@@ -470,7 +470,9 @@ class JobHandle:
         assert self.controller is not None
         out: List[EpochRecord] = []
         for _ in range(epochs):
-            record, result = run_backend_epoch(self.controller, self.backend, steps=steps)
+            record, result = run_backend_epoch(
+                self.controller, self.backend, steps=steps, epoch_index=self.epochs_run
+            )
             self.last_result = result
             self.sim_time += record.epoch_seconds
             self.epochs_run += 1
