@@ -261,9 +261,25 @@ STATE_DONATION = (0, 1)
 # carries one, so a profiler trace splits the step's device time by phase.
 SCOPE_BACKWARD = "node_backward"        # the per-node backward
 SCOPE_AGGREGATE = "eq9_aggregate"       # poison, sq-norms, guard, Eq. 9, |g|^2
-SCOPE_LOSS = "loss_forward"             # the step's reported loss
+SCOPE_LOSS = "loss_forward"             # the reported loss, from per-node means
 SCOPE_UPDATE = "optimizer_update"
 STEP_SCOPES = (SCOPE_BACKWARD, SCOPE_AGGREGATE, SCOPE_LOSS, SCOPE_UPDATE)
+
+
+def composed_loss_terms(losses: Any, mask: Any, seq_len: int) -> Tuple[Any, Any]:
+    """``(numerator, denominator)`` of the global mean loss composed from
+    the per-node mean losses ``losses`` (n,) of a dense family.
+
+    Node i's token-weight sum is ``mask_i.sum() * seq_len``
+    (``registry._token_loss`` broadcasts the per-sample mask over the
+    sequence dim), so ``losses_i * w_sum_i`` recovers its loss summand and
+    an all-padding node adds 0.  The quotient equals the full-batch
+    weighted-mean cross-entropy to float32 round-off; the caller divides,
+    after any cross-shard ``psum`` of both terms."""
+    import jax.numpy as jnp
+
+    w_sum = mask.sum(axis=1) * jnp.float32(seq_len)
+    return (losses.astype(jnp.float32) * w_sum).sum(), w_sum.sum()
 
 
 def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callable:
@@ -272,9 +288,14 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
 
     ``step(params, opt_state, tokens, labels, mask, r, lr_scale, poison)
     -> (params, opt_state, loss, sq_i, sq_g, valid)`` over the padded
-    ``(n, b_max)`` node layout.  Module-level so a compile-only check can
-    build the exact program from abstract shapes.  Its phases run under
-    the named scopes of ``STEP_SCOPES``."""
+    ``(n, b_max)`` node layout.  The reported ``loss`` is composed from
+    the per-node mean losses the backward's own forward computes
+    (:func:`composed_loss_terms`), so the step runs no second forward;
+    where ``api.has_moe_stats`` the load-balance and z-loss terms are
+    whole-batch statistics that do not compose, and the loss is one
+    full-batch forward at the pre-update params.  Module-level so a
+    compile-only check can build the exact program from abstract shapes.
+    Its phases run under the named scopes of ``STEP_SCOPES``."""
     import jax
     import jax.numpy as jnp
 
@@ -289,14 +310,20 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
         )
         return loss
 
-    grad_fn = jax.grad(node_loss)
+    val_grad = jax.value_and_grad(node_loss)
 
     def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
         # tokens/labels: (n, b_max, S); mask: (n, b_max); r/poison: (n,)
         with jax.named_scope(SCOPE_BACKWARD):
-            grads = jax.vmap(grad_fn, in_axes=(None, 0, 0, 0))(
+            losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
                 params, tokens, labels, mask
             )
+            # The aggregate starts once the whole backward is done.  Left
+            # free, XLA interleaves it with the backward, and chip_smoke's
+            # fused epoch compiles to 15.3 GB for a v5e (14.4 GB with the
+            # barrier).  The barrier is an identity, though XLA may then
+            # round the aggregate's sums in another order.
+            losses, grads = jax.lax.optimization_barrier((losses, grads))
         with jax.named_scope(SCOPE_AGGREGATE):
             grads = jax.tree_util.tree_map(
                 lambda g: g
@@ -317,14 +344,18 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
             )
             sq_g = global_norm(agg) ** 2
         with jax.named_scope(SCOPE_LOSS):
-            loss, _ = api.loss(
-                params,
-                {
-                    "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
-                    "labels": labels.reshape((-1,) + labels.shape[2:]),
-                    "weights": mask.reshape(-1),
-                },
-            )
+            if api.has_moe_stats:
+                loss, _ = api.loss(
+                    params,
+                    {
+                        "tokens": tokens.reshape((-1,) + tokens.shape[2:]),
+                        "labels": labels.reshape((-1,) + labels.shape[2:]),
+                        "weights": mask.reshape(-1),
+                    },
+                )
+            else:
+                num, den = composed_loss_terms(losses, mask, tokens.shape[-1])
+                loss = num / jnp.maximum(den, 1e-9)
         with jax.named_scope(SCOPE_UPDATE):
             new_params, new_opt = optimizer.update(agg, opt_state, params, lr_scale)
         return new_params, new_opt, loss, sq_i, sq_g, valid
@@ -345,10 +376,12 @@ def sharded_node_step_body(
     psums its weighted local partials into the replicated Eq. (9)
     aggregate.  The optimizer update runs outside shard_map on the
     replicated aggregate.  The global loss is composed from per-node
-    means by their token-weight sums — algebraically equal to the vmap
-    path's full-batch forward (see models.registry._token_loss), to
-    float32 roundoff.  The phases carry the scopes of ``STEP_SCOPES``; the
-    composed loss runs under ``SCOPE_LOSS``."""
+    means by their token-weight sums (:func:`composed_loss_terms`, both
+    terms psum'd): for dense families it equals a full-batch forward (see
+    models.registry._token_loss) to float32 roundoff; an MoE family's
+    load-balance and z-loss terms are whole-batch statistics, which this
+    composition leaves as per-node means.  The phases carry the scopes of
+    ``STEP_SCOPES``; the composed loss runs under ``SCOPE_LOSS``."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -404,13 +437,9 @@ def sharded_node_step_body(
             )
             sq_g = global_norm(agg) ** 2
         with jax.named_scope(SCOPE_LOSS):
-            # Global mean loss from per-node means: node i's token-weight
-            # sum is mask_i.sum() * S (registry._token_loss broadcasts the
-            # per-sample mask over the sequence dim), and loss_i * w_sum_i
-            # recovers its loss summand; all-padding nodes contribute 0.
-            w_sum = mask.sum(axis=1) * jnp.float32(tokens.shape[-1])
-            loss_num = jax.lax.psum((losses * w_sum).sum(), "nodes")
-            loss_den = jax.lax.psum(w_sum.sum(), "nodes")
+            num, den = composed_loss_terms(losses, mask, tokens.shape[-1])
+            loss_num = jax.lax.psum(num, "nodes")
+            loss_den = jax.lax.psum(den, "nodes")
             loss = loss_num / jnp.maximum(loss_den, 1e-9)
         return agg, loss, sq_i, sq_g, valid
 
