@@ -15,10 +15,11 @@ that seam:
   behind the protocol: timing measurements only, no gradients (losses are
   NaN).  The default for trace replay.
 * :class:`RealBackend` — the gradient engine extracted from the old
-  ``HeteroTrainer``: one vmapped per-node backward over the padded
-  ``(n, b_max)`` layout, Eq. (9) weighted aggregation, a Theorem-4.1 GNS
-  tracker, and a simulated cluster clock (the paper's own separation:
-  statistical behaviour is real, per-node timing is simulated).  Its state
+  ``HeteroTrainer``: the vmapped per-node backward over the padded
+  ``(n, b_max)`` layout, each node at its own bucketed width, Eq. (9)
+  weighted aggregation, a Theorem-4.1 GNS tracker, and a simulated
+  cluster clock (the paper's own separation: statistical behaviour is
+  real, per-node timing is simulated).  Its state
   (params / opt-state / GNS / stream counters) round-trips bit-exactly
   through :mod:`repro.train.checkpoint` for preemption/resume.
 * :func:`run_backend_epoch` / :class:`EpochLoop` — the policy loop the
@@ -282,20 +283,46 @@ def composed_loss_terms(losses: Any, mask: Any, seq_len: int) -> Tuple[Any, Any]
     return (losses.astype(jnp.float32) * w_sum).sum(), w_sum.sum()
 
 
-def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callable:
+def width_groups(widths: Sequence[int]) -> Tuple[Tuple[int, Tuple[int, ...]], ...]:
+    """Node indices grouped by padded width, in order of first appearance:
+    ``(16, 8, 8)`` gives ``((16, (0,)), (8, (1, 2)))``."""
+    groups: Dict[int, List[int]] = {}
+    for i, w in enumerate(widths):
+        groups.setdefault(int(w), []).append(i)
+    return tuple((w, tuple(idx)) for w, idx in groups.items())
+
+
+def node_step_body(
+    api: Any,
+    optimizer: Any,
+    *,
+    outlier_factor: float,
+    widths: Optional[Sequence[int]] = None,
+) -> Callable:
     """The un-jitted single-device RealBackend step: vmapped per-node
     backward, anomaly-guarded Eq. (9) aggregate, one optimizer update.
 
     ``step(params, opt_state, tokens, labels, mask, r, lr_scale, poison)
     -> (params, opt_state, loss, sq_i, sq_g, valid)`` over the padded
-    ``(n, b_max)`` node layout.  The reported ``loss`` is composed from
-    the per-node mean losses the backward's own forward computes
-    (:func:`composed_loss_terms`), so the step runs no second forward;
-    where ``api.has_moe_stats`` the load-balance and z-loss terms are
-    whole-batch statistics that do not compose, and the loss is one
-    full-batch forward at the pre-update params.  Module-level so a
-    compile-only check can build the exact program from abstract shapes.
-    Its phases run under the named scopes of ``STEP_SCOPES``."""
+    ``(n, b_max)`` node layout.  ``widths`` (static, one per node, each at
+    most ``b_max``; None: ``b_max`` for every node) are the rows each node's
+    backward computes: node i's real rows are ``[i, :b_i]`` with
+    ``b_i <= widths[i]``, so the rows past its width carry zero weight and
+    are dropped.  Nodes of equal width share one vmapped backward, each
+    group at its own width; with one width the step is the plain vmap over
+    the whole layout.  The groups' gradients are never concatenated: the
+    Eq. (9) aggregate sums each group's weighted share.  An expert
+    family's router counts every row it is given (capacity, load-balance
+    statistics), so its node gradients are those at the node's own width.
+
+    The reported ``loss`` is composed from the per-node mean losses the
+    backward's own forward computes (:func:`composed_loss_terms`), so the
+    step runs no second forward; where ``api.has_moe_stats`` the
+    load-balance and z-loss terms are whole-batch statistics that do not
+    compose, and the loss is one full-batch forward at the pre-update
+    params.  Module-level so a compile-only check can build the exact
+    program from abstract shapes.  Its phases run under the named scopes
+    of ``STEP_SCOPES``."""
     import jax
     import jax.numpy as jnp
 
@@ -314,34 +341,77 @@ def node_step_body(api: Any, optimizer: Any, *, outlier_factor: float) -> Callab
 
     def step(params, opt_state, tokens, labels, mask, r, lr_scale, poison):
         # tokens/labels: (n, b_max, S); mask: (n, b_max); r/poison: (n,)
+        n, b_max = mask.shape
+        ws = tuple(int(w) for w in widths) if widths is not None else (b_max,) * n
+        if len(ws) != n or max(ws) > b_max:
+            raise ValueError(f"widths {ws} do not fit the layout ({n}, {b_max})")
+        groups = width_groups(ws)
+        nodes = tuple(range(n))
+
+        def pick(x, idx):
+            # Static node rows ``idx`` of a node-major array.
+            return x if idx == nodes else x[np.asarray(idx)]
+
+        def rows(x, idx, width):
+            x = pick(x, idx)
+            return x if width == b_max else x[:, :width]
+
+        inverse = np.argsort([i for _, idx in groups for i in idx])
+
+        def in_node_order(parts):
+            # (n,) in node order from one vector per group.
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts)[inverse]
+
         with jax.named_scope(SCOPE_BACKWARD):
-            losses, grads = jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
-                params, tokens, labels, mask
-            )
+            outs = [
+                jax.vmap(val_grad, in_axes=(None, 0, 0, 0))(
+                    params,
+                    rows(tokens, idx, width),
+                    rows(labels, idx, width),
+                    rows(mask, idx, width),
+                )
+                for width, idx in groups
+            ]
             # The aggregate starts once the whole backward is done.  Left
             # free, XLA interleaves it with the backward, and chip_smoke's
             # fused epoch compiles to 15.3 GB for a v5e (14.4 GB with the
             # barrier).  The barrier is an identity, though XLA may then
             # round the aggregate's sums in another order.
-            losses, grads = jax.lax.optimization_barrier((losses, grads))
+            outs = jax.lax.optimization_barrier(outs)
         with jax.named_scope(SCOPE_AGGREGATE):
-            grads = jax.tree_util.tree_map(
-                lambda g: g
-                * poison.reshape((-1,) + (1,) * (g.ndim - 1)).astype(g.dtype),
-                grads,
-            )
-            sq_i = jax.vmap(lambda g: global_norm(g) ** 2)(grads)
+            grads, sq_parts = [], []
+            for (_, idx), (_, g) in zip(groups, outs):
+                p = pick(poison, idx)
+                g = jax.tree_util.tree_map(
+                    lambda a: a
+                    * p.reshape((-1,) + (1,) * (a.ndim - 1)).astype(a.dtype),
+                    g,
+                )
+                grads.append(g)
+                sq_parts.append(jax.vmap(lambda g: global_norm(g) ** 2)(g))
+            sq_i = in_node_order(sq_parts)
+            losses = in_node_order([loss for loss, _ in outs])
             w, valid = guard_weights(sq_i, r, outlier_factor=outlier_factor)
-            agg = jax.tree_util.tree_map(
-                lambda g: jnp.tensordot(
-                    w.astype(jnp.float32),
-                    jnp.where(
-                        valid.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
-                    ).astype(jnp.float32),
-                    axes=1,
-                ).astype(g.dtype),
-                grads,
-            )
+            shares = [
+                (pick(w, idx).astype(jnp.float32), pick(valid, idx))
+                for _, idx in groups
+            ]
+
+            def weighted_sum(*leaves):
+                # Eq. (9) for one leaf: each group's share, summed in f32.
+                total = None
+                for (w_g, valid_g), g in zip(shares, leaves):
+                    part = jnp.tensordot(
+                        w_g,
+                        jnp.where(
+                            valid_g.reshape((-1,) + (1,) * (g.ndim - 1)), g, 0
+                        ).astype(jnp.float32),
+                        axes=1,
+                    )
+                    total = part if total is None else total + part
+                return total.astype(leaves[0].dtype)
+
+            agg = jax.tree_util.tree_map(weighted_sum, *grads)
             sq_g = global_norm(agg) ** 2
         with jax.named_scope(SCOPE_LOSS):
             if api.has_moe_stats:
@@ -550,8 +620,9 @@ def fused_epoch_body(step_body: Callable, n: int, *, gns_decay: float) -> Callab
 
 
 def _quantize(b: int, q: int = 8) -> int:
-    """Quantize the padded per-node width so epoch-to-epoch repartitioning
-    reuses compiled steps (recompilation hygiene; EXPERIMENTS.md §Perf)."""
+    """A node's padded width: ``b`` rounded up to a multiple of ``q`` (at
+    least ``q``), so nearby splits map to one width and repartitioning
+    from epoch to epoch reuses the compiled steps."""
     return max(q, ((b + q - 1) // q) * q)
 
 
@@ -559,7 +630,8 @@ class RealBackend:
     """The real-gradient engine (extracted from the old ``HeteroTrainer``).
 
     Per step: partition the global batch by the planned local batch sizes,
-    run one vmapped per-node backward over the padded ``(n, b_max)`` layout,
+    run the vmapped per-node backward over the padded ``(n, b_max)``
+    layout, each node at its own bucketed width (:func:`_quantize`), then
     aggregate g = sum r_i g_i (Eq. 9), update params once, feed
     (|g_i|^2, |g|^2, b) to the Theorem-4.1 GNS tracker, and advance the
     simulated cluster clock by the heterogeneous batch time.
@@ -634,7 +706,7 @@ class RealBackend:
         self.anomalous_steps = 0       # steps with >= 1 excluded node (lifetime)
         self.sharded = bool(sharded)
         self.telemetry = Telemetry()
-        self._step_cache: Dict[int, Callable] = {}
+        self._step_cache: Dict[Tuple[int, ...], Callable] = {}
         self._sharded_cache: Dict[Tuple[int, int], Callable] = {}
         self._fused_cache: Dict[Tuple[int, int], Callable] = {}
         self._meshes: Dict[int, Any] = {}        # shard count -> Mesh
@@ -698,9 +770,10 @@ class RealBackend:
 
     # -- gradient engine -------------------------------------------------
 
-    def _node_grad_fn(self, b_max: int) -> Callable:
+    def _node_grad_fn(self, widths: Tuple[int, ...]) -> Callable:
         """Jitted: per-node grads (× injected poison factors) + sq-norms +
-        anomaly-guarded Eq.(9) aggregate + update.
+        anomaly-guarded Eq.(9) aggregate + update, each node's backward at
+        its padded width in ``widths``; one program per distinct ``widths``.
 
         The guard is *always* compiled in — the same program runs with and
         without an injector, so the no-fault bit-identity guarantee holds
@@ -710,21 +783,23 @@ class RealBackend:
         *before* the tensordot (0 × NaN = NaN otherwise) and the surviving
         weights renormalized; with every node invalid the aggregate is
         zero and the update a no-op."""
-        if b_max in self._step_cache:
-            return self._step_cache[b_max]
+        if widths in self._step_cache:
+            return self._step_cache[widths]
         import jax
 
-        fn = jax.jit(self._build_step_body(), donate_argnums=STATE_DONATION)
-        self._step_cache[b_max] = fn
+        fn = jax.jit(self._build_step_body(widths), donate_argnums=STATE_DONATION)
+        self._step_cache[widths] = fn
         self.telemetry.programs_built += 1
         return fn
 
-    def _build_step_body(self) -> Callable:
+    def _build_step_body(self, widths: Optional[Tuple[int, ...]] = None) -> Callable:
         """The un-jitted single-device step (vmapped per-node backward).
-        Shared verbatim between :meth:`_node_grad_fn` (jitted directly) and
-        the fused epoch program (scanned inside one jit)."""
+        Shared between :meth:`_node_grad_fn` (jitted directly, at the
+        planned widths) and the fused epoch program (scanned inside one
+        jit, over the uniform layout)."""
         return node_step_body(
-            self.api, self.optimizer, outlier_factor=self.outlier_factor
+            self.api, self.optimizer, outlier_factor=self.outlier_factor,
+            widths=widths,
         )
 
     def _build_sharded_step_body(self, n: int) -> Callable:
@@ -762,17 +837,21 @@ class RealBackend:
 
         batches = [int(b) for b in batches]
         b_arr = np.asarray(batches, np.int64)
-        b_max = _quantize(int(b_arr.max()))
+        widths = tuple(_quantize(b) for b in batches)
+        b_max = max(widths)
         n = len(batches)
         tel = self.telemetry
         r = tel.put(np.asarray(ratios(batches), np.float32), jnp.asarray)
         use_sharded = self.sharded and n > 0
         if use_sharded:
+            # The node mesh computes the uniform layout.
             step_fn = self._node_grad_fn_sharded(n)
             place = self._node_placer(n)
+            rows_computed, ragged = n * b_max, False
         else:
-            step_fn = self._node_grad_fn(b_max)
+            step_fn = self._node_grad_fn(widths)
             place = jnp.asarray
+            rows_computed, ragged = sum(widths), len(set(widths)) > 1
 
         node_ids = self._node_ids if len(self._node_ids) == n else tuple(range(n))
         if self.injector is not None:
@@ -799,7 +878,7 @@ class RealBackend:
                     tok[:, :w], lab[:, :w] = padded["tokens"], padded["labels"]
                     for i, b in enumerate(batches):
                         msk[i, :b] = 1.0
-                tel.count_steps(1, int(b_arr.sum()), n * b_max)
+                tel.count_steps(1, int(b_arr.sum()), rows_computed, ragged=ragged)
                 with TraceAnnotation("repro.step.put", step_num=step_num):
                     args = tuple(tel.put(a, place) for a in (tok, lab, msk))
                     lr = tel.put(np.float32(lr_scale), jnp.asarray)

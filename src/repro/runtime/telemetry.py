@@ -10,8 +10,11 @@ where the work happens, never written in by hand:
   Python scalars handed to ``jit`` as arguments are shipped by ``jit``
   itself and are not counted;
 * each step adds the rows the plan assigned (``rows_useful``, the sum of
-  the per-node batches) and the rows the ``(n, b_max)`` layout computes
-  (``rows_computed``), so padding is ``rows_computed - rows_useful``;
+  the per-node batches) and the rows its backward computes
+  (``rows_computed``: the sum of the per-node padded widths, ``n x b_max``
+  where every node runs at ``b_max``), so padding is ``rows_computed -
+  rows_useful``; ``steps_ragged`` counts the steps whose nodes ran at
+  more than one width;
 * each miss of a step-program cache (two-program, sharded, fused) adds one
   to ``programs_built``.  A cached jit that retraces for a new shape is
   not a miss.
@@ -37,6 +40,7 @@ class Telemetry:
     steps: int = 0
     rows_useful: int = 0
     rows_computed: int = 0
+    steps_ragged: int = 0
     h2d_arrays: int = 0
     h2d_bytes: int = 0
     d2h_arrays: int = 0
@@ -62,10 +66,13 @@ class Telemetry:
             self.d2h_bytes += int(np.asarray(leaf).nbytes)
         return host
 
-    def count_steps(self, steps: int, rows_useful: int, rows_computed: int) -> None:
+    def count_steps(
+        self, steps: int, rows_useful: int, rows_computed: int, *, ragged: bool = False
+    ) -> None:
         """``steps`` steps, each over ``rows_useful`` planned rows padded to
-        ``rows_computed``."""
+        ``rows_computed``; ``ragged``: its nodes ran at more than one width."""
         self.steps += int(steps)
+        self.steps_ragged += int(steps) * bool(ragged)
         self.rows_useful += int(steps) * int(rows_useful)
         self.rows_computed += int(steps) * int(rows_computed)
 

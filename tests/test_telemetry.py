@@ -15,7 +15,7 @@ from repro.core.simulator import GPU_CATALOG  # noqa: E402
 from repro.runtime import EpochLoop, RealBackendConfig  # noqa: E402
 from repro.runtime.backend import STEP_SCOPES  # noqa: E402
 
-SPLIT = [14, 7, 3]          # padded to b_max 16: 48 rows computed for 24
+SPLIT = [14, 7, 3]          # widths 16/8/8: 32 rows computed for 24
 SEQ = 16
 
 
@@ -49,8 +49,19 @@ def test_two_program_epoch_counts_padded_rows():
     backend.execute(SPLIT, 2)
     t = backend.telemetry.snapshot()
     assert t["steps"] == 2
-    assert t["rows_computed"] == 2 * 48
+    assert t["rows_computed"] == 2 * 32
     assert t["rows_useful"] == 2 * 24
+    assert t["steps_ragged"] == 2
+
+
+def test_two_program_epoch_at_one_width_counts_no_ragged_step():
+    backend = _backend()
+    backend.execute([4, 4, 4], 2)
+    t = backend.telemetry.snapshot()
+    assert t["steps"] == 2
+    assert t["rows_computed"] == 2 * 24
+    assert t["rows_useful"] == 2 * 12
+    assert t["steps_ragged"] == 0
 
 
 def test_observed_transfers_per_step_are_four_each_way():
@@ -84,6 +95,7 @@ def test_fused_epoch_counts_rows_per_scanned_step():
     assert d["steps"] == 3
     assert d["rows_useful"] == 3 * sum(rec.batches)
     assert d["rows_computed"] == 3 * 3 * b_max
+    assert d["steps_ragged"] == 0  # the fused epoch runs the uniform layout
     # One shipment of the epoch (10 arrays) and one 13-leaf bundle back.
     assert d["h2d_arrays"] == 10 and d["d2h_arrays"] == 13
 
@@ -98,11 +110,27 @@ def test_programs_built_counts_step_cache_misses():
     assert backend.telemetry.programs_built == 2
 
 
-@pytest.mark.parametrize("sharded", [False, True])
-def test_lowered_step_carries_the_phase_scopes(sharded):
+def test_splits_of_equal_widths_share_a_program():
+    backend = _backend()
+    for split in ([14, 7, 3], [15, 7, 2]):   # widths 16/8/8
+        backend.execute(split, 1)
+    assert backend.telemetry.programs_built == 1
+    for split in ([4, 4, 4], [5, 5, 2]):     # widths 8/8/8
+        backend.execute(split, 1)
+    assert backend.telemetry.programs_built == 2
+    assert set(backend._step_cache) == {(16, 8, 8), (8, 8, 8)}
+    assert backend.telemetry.steps_ragged == 2
+
+
+@pytest.mark.parametrize(
+    "sharded, widths",
+    [(False, (16, 16, 16)), (True, None), (False, (16, 8, 8))],
+    ids=["False", "True", "ragged"],
+)
+def test_lowered_step_carries_the_phase_scopes(sharded, widths):
     backend = _backend(sharded=sharded)
     n, b_max = 3, 16
-    fn = backend._node_grad_fn_sharded(n) if sharded else backend._node_grad_fn(b_max)
+    fn = backend._node_grad_fn_sharded(n) if sharded else backend._node_grad_fn(widths)
     tok = np.zeros((n, b_max, SEQ), np.int32)
     vec = np.ones((n,), np.float32)
     text = fn.lower(

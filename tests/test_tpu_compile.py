@@ -126,16 +126,20 @@ def test_flash_attention_kernel_compiles_at_olmo_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("program", ["step", "fused_epoch"])
+@pytest.mark.parametrize("program", ["step", "fused_epoch", "ragged_step"])
 def test_smoke_programs_fit_one_chip(one_chip, program):
     """The chip smoke's largest programs -- 3 cluster-A nodes at b_max 16
-    (its per-node cap), its depth and sequence cuts -- fit 15 GB of HBM."""
+    (its per-node cap), its depth and sequence cuts -- fit 15 GB of HBM;
+    ``ragged_step`` is the step at the 14/7/3 split's widths 16/8/8."""
     n, b_max, seq = 3, chip_smoke.MAX_LOCAL, chip_smoke.SEQ
     api, opt, params, opt_state = _model_state(chip_smoke.LAYERS, one_chip)
-    step = node_step_body(api, opt, outlier_factor=ANOMALY_OUTLIER_FACTOR)
+    widths = (b_max, b_max // 2, b_max // 2) if program == "ragged_step" else None
+    step = node_step_body(
+        api, opt, outlier_factor=ANOMALY_OUTLIER_FACTOR, widths=widths
+    )
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)  # noqa: E731
-    if program == "step":
+    if program != "fused_epoch":
         fn = step
         args = (i32(n, b_max, seq), i32(n, b_max, seq), f32(n, b_max),
                 f32(n), f32(), f32(n))
